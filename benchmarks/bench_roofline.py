@@ -24,8 +24,9 @@ def _backfill_fraction(r):
     mb = rl.model_min_bytes_for(cfg, info["kind"], info["batch"],
                                 info["seq"])
     t_bound = max(r["t_compute"], r["t_memory"], r["t_collective"])
-    t_ideal = max(float(r["model_flops"]) / n_chips / rl.PEAK_FLOPS_BF16,
-                  mb / n_chips / rl.HBM_BW)
+    chip = rl.peaks(rl.DRYRUN_DEVICE_KIND)
+    t_ideal = max(float(r["model_flops"]) / n_chips / chip.flops_bf16,
+                  mb / n_chips / chip.hbm_bw)
     r["min_bytes"] = mb
     r["t_ideal"] = t_ideal
     r["roofline_fraction"] = t_ideal / t_bound if t_bound else 0.0

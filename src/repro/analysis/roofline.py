@@ -28,11 +28,39 @@ import json
 import re
 from typing import Dict, Optional, Tuple
 
-# --- TPU v5e-class hardware constants (per chip) ---------------------------
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-PEAK_OPS_INT8 = 394e12
-HBM_BW = 819e9                    # B/s
-ICI_BW_PER_LINK = 50e9            # B/s per link (assignment constant)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator generation."""
+
+    flops_bf16: float             # FLOP/s
+    ops_int8: float               # OP/s
+    hbm_bw: float                 # B/s
+    ici_bw_per_link: float        # B/s per link
+
+
+# Keyed by ``jax.Device.device_kind``. Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, ops_int8=393e12,
+                             hbm_bw=819e9, ici_bw_per_link=50e9),
+}
+
+# The chip the dry-run meshes (launch/mesh.py) describe.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; a device without published
+    peaks (the CPU, an unlisted TPU) raises instead of borrowing another
+    chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; have {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -104,16 +132,20 @@ class Roofline:
     min_bytes: float = 0.0        # inherent minimal HBM traffic (global)
 
     @property
+    def chip(self) -> ChipPeaks:
+        return peaks(DRYRUN_DEVICE_KIND)
+
+    @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS_BF16
+        return self.flops / self.chip.flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.chip.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / ICI_BW_PER_LINK
+        return self.coll_bytes / self.chip.ici_bw_per_link
 
     @property
     def bottleneck(self) -> str:
@@ -138,8 +170,8 @@ class Roofline:
         traffic at full bandwidth). Decode steps are intrinsically
         memory-bound -- every parameter and cache byte must be read once
         per token -- so their roof is the memory term, not compute."""
-        t_c = self.model_flops / self.n_chips / PEAK_FLOPS_BF16
-        t_m = self.min_bytes / self.n_chips / HBM_BW
+        t_c = self.model_flops / self.n_chips / self.chip.flops_bf16
+        t_m = self.min_bytes / self.n_chips / self.chip.hbm_bw
         return max(t_c, t_m)
 
     @property
@@ -171,8 +203,6 @@ def analyze(compiled, lowered_text: Optional[str], *, arch: str, shape: str,
     text = lowered_text if lowered_text is not None else compiled.as_text()
     rep = hlo_lib.analyze_hlo(text)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):      # jax < 0.6 returns one dict per device
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     hbm_peak = float(ma.argument_size_in_bytes + ma.output_size_in_bytes +
                      ma.temp_size_in_bytes) if ma else 0.0

@@ -183,11 +183,15 @@ class ExecutionContext:
 
     @property
     def sharded(self) -> bool:
-        """True when dispatch wraps kernels in shard_map: a mesh is set
-        AND the backend runs real kernel bodies (the xla reference and
-        the xla_twin are already SPMD-partitionable; GSPMD owns them)."""
+        """True when dispatch wraps kernels in shard_map: a mesh of more
+        than one device is set AND the backend runs real kernel bodies
+        (the xla reference and the xla_twin are already
+        SPMD-partitionable; GSPMD owns them). A Mosaic kernel cannot be
+        partitioned automatically, so the wrap applies even when the
+        batch axis has one device (a ``(data=1, model=N)`` mesh): the
+        kernel then runs whole on every device."""
         return self.mesh is not None and self.impl_backend != "xla" \
-            and self.n_shards > 1
+            and self.mesh.size > 1
 
     # -- dispatch ----------------------------------------------------------
     @contextlib.contextmanager
@@ -217,10 +221,6 @@ class ExecutionContext:
         """
         import jax
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:                       # newer jax: jax.shard_map
-            shard_map = jax.shard_map
         n = self.n_shards
         if not self.sharded or any(
                 b and (a.shape[0] % n != 0 or a.shape[0] < n)
@@ -232,10 +232,10 @@ class ExecutionContext:
         def out_spec(b):
             return bspec if b else P()
 
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs,
             out_specs=jax.tree.map(out_spec, out_batched),
-            check_rep=False)
+            check_vma=False)
         return wrapped(*arrays)
 
     def __getattr__(self, name: str):
@@ -253,6 +253,14 @@ class ExecutionContext:
             fn = _profiled_op(name, fn, prof, self)
         inj = _fault_injector()
         return fn if inj is None else _faulted_op(name, fn, inj)
+
+
+def _tracing() -> bool:
+    """True while JAX traces (jit, grad, scan bodies): the op hooks below
+    then pass through, since their host-side work would be baked into the
+    compiled program or would block on tracers."""
+    import jax
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def _profiler():
@@ -279,10 +287,9 @@ def _profiled_op(name: str, fn: Callable, prof, ctx) -> Callable:
 
     @functools.wraps(fn)
     def wrapped(*args, **kw):
-        import jax
-        clean = getattr(jax.core, "trace_state_clean", None)
-        if clean is not None and not clean():
+        if _tracing():
             return fn(*args, **kw)
+        import jax
         bucket = prof.bucket(name, args, kw, ctx.cfg)
         t0 = prof.clock()
         out = fn(*args, **kw)
@@ -318,9 +325,7 @@ def _faulted_op(name: str, fn: Callable, inj) -> Callable:
 
     @functools.wraps(fn)
     def wrapped(*args, **kw):
-        import jax
-        clean = getattr(jax.core, "trace_state_clean", None)
-        if clean is not None and not clean():
+        if _tracing():
             return fn(*args, **kw)
         site = f"op:{name}"
         inj.check_transient(site)

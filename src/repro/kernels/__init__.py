@@ -1,12 +1,3 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-
-from jax.experimental.pallas import tpu as _pltpu
-
-
-def tpu_compiler_params(**kw):
-    """Mosaic compiler params across jax versions: the class was named
-    ``TPUCompilerParams`` before jax 0.7 and ``CompilerParams`` after."""
-    cls = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
-    return cls(**kw)

@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-import repro.kernels as kernels_pkg
 from repro.kernels.contracts import kernel_contract
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -178,7 +177,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=kernels_pkg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -202,7 +201,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = len_ref[0]                     # current position (keys <= pos live)
+    pos = len_ref[pl.program_id(0)]      # current position (keys <= pos live)
     k0 = j * block_k
     # Same skip predicate as the prefill kernel with q0 = pos and block_q=1;
     # the k0 < tk padding term skips blocks fully in the pad_k region (pos
@@ -280,8 +279,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, rep, d), lambda g, j: (g, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda g, j: (g, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda g, j: (g, j, 0)),
-            pl.BlockSpec((1,), lambda g, j: (g,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),      # all lengths
         ],
         out_specs=pl.BlockSpec((1, rep, d), lambda g, j: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * kvh, rep, d), q.dtype),
@@ -290,7 +288,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((rep,), jnp.float32),
             pltpu.VMEM((rep, d), jnp.float32),
         ],
-        compiler_params=kernels_pkg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qg, kt, vt, lens)
@@ -419,7 +417,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
-        compiler_params=kernels_pkg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(bt, lens, qg, k_pool, v_pool)
@@ -559,7 +557,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, nq * block_q, d), q.dtype),
-        compiler_params=kernels_pkg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(bt, start_arr, qt, k_pool, v_pool)

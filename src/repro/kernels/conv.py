@@ -33,8 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-import repro.kernels as kernels_pkg
-
 from repro.core.config import Activation, GemminiConfig
 from repro.kernels import epilogue as epi
 from repro.kernels.contracts import kernel_contract
@@ -146,7 +144,7 @@ def conv2d_implicit(x: jnp.ndarray, w: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((n, oh, ow, nco * co_tile),
                                        cfg.output_jnp),
         scratch_shapes=[pltpu.VMEM((oh * ow, co_tile), cfg.acc_jnp)],
-        compiler_params=kernels_pkg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

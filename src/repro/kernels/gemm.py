@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-import repro.kernels as kernels_pkg
-
 from repro.core.config import Activation, Dataflow, GemminiConfig
 from repro.core.tiling import TilePlan
 from repro.kernels import epilogue as epi
@@ -115,7 +113,7 @@ def gemm_os(a: jnp.ndarray, b: jnp.ndarray, d: Optional[jnp.ndarray],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), cfg.output_jnp),
         scratch_shapes=[pltpu.VMEM((tm, tn), cfg.acc_jnp)],
-        compiler_params=kernels_pkg.tpu_compiler_params(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )(a, b, d)
 
@@ -194,7 +192,7 @@ def gemm_ws(a: jnp.ndarray, b: jnp.ndarray, d: Optional[jnp.ndarray],
         out_specs=pl.BlockSpec((tm, tn), lambda j, i, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), cfg.output_jnp),
         scratch_shapes=[pltpu.VMEM((tm, tn), cfg.acc_jnp)],
-        compiler_params=kernels_pkg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
             if cfg.pipeline_depth > 1 else ("arbitrary",) * 3),
         interpret=interpret,
@@ -223,7 +221,7 @@ def accumulator_epilogue(acc: jnp.ndarray, plan: TilePlan, cfg: GemminiConfig,
         out_shape=jax.ShapeDtypeStruct((m, n), cfg.output_jnp),
         # every tile is independent: both axes pipeline freely (found by
         # lint GL503 — an undeclared grid serializes under Mosaic)
-        compiler_params=kernels_pkg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(acc)

@@ -43,7 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-import repro.kernels as kernels_pkg
 from repro.kernels.contracts import kernel_contract
 
 
@@ -60,43 +59,58 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, y_ref, *rest,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    a = a_ref[0]                                   # scalar: -exp(a_log)
-    d_skip = d_ref[0]                              # scalar skip weight
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)       # (Q,)
+    hh = pl.program_id(1)
+    a = a_ref[hh]                                  # scalar: -exp(a_log)
+    d_skip = d_ref[hh]                             # scalar skip weight
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)       # (1, Q) row
     x = x_ref[0, 0, 0].astype(jnp.float32)         # (Q, P)
     b = b_ref[0, 0, 0].astype(jnp.float32)         # (Q, N)
     c = c_ref[0, 0, 0].astype(jnp.float32)         # (Q, N)
 
-    dta = dt * a                                   # (Q,)
-    seg = jnp.cumsum(dta)                          # inclusive cumsum
-
-    # intra-chunk decay L[i, j] = exp(seg_i - seg_j) for i >= j else 0
-    li = seg[:, None] - seg[None, :]
+    # Mosaic has no cumsum and no row->column relayout of a vector, so the
+    # inclusive prefix sum and the column forms come from exact f32 GEMMs
+    # against the lower-triangular ones and the identity:
+    #   seg_r = dta @ tril^T (1, Q),  seg_c = tril @ dta^T (Q, 1).
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    ldec = jnp.where(ii >= jj, jnp.exp(li), 0.0)
+    tril = (ii >= jj).astype(jnp.float32)
+    eye = (ii == jj).astype(jnp.float32)
+    nt = (((1,), (1,)), ((), ()))
+    hi = jax.lax.Precision.HIGHEST
+
+    def _mm(u, v):
+        return jax.lax.dot_general(u, v, nt, precision=hi,
+                                   preferred_element_type=jnp.float32)
+
+    dta = dt * a                                   # (1, Q)
+    seg_r = _mm(dta, tril)                         # (1, Q) inclusive cumsum
+    seg_c = _mm(tril, dta)                         # (Q, 1) the same, column
+    dt_c = _mm(eye, dt)                            # (Q, 1)
+
+    # intra-chunk decay L[i, j] = exp(seg_i - seg_j) for i >= j else 0
+    ldec = jnp.where(ii >= jj, jnp.exp(seg_c - seg_r), 0.0)
 
     # scores = C_i . B_j  (Q, Q): a GEMM on the engine schedule
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    y = jax.lax.dot_general(scores * ldec * dt[None, :], x,
+    y = jax.lax.dot_general(scores * ldec * dt, x,
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # contribution of the carried-in state to every step of this chunk
     y_off = jax.lax.dot_general(c, state_ref[...], (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    y = y + y_off * jnp.exp(seg)[:, None]
+    y = y + y_off * jnp.exp(seg_c)
     # fused epilogue: the d_skip residual rides the same f32 accumulator
     # (zero when the model has no skip weight -- an exact no-op)
     y_ref[0, 0, 0] = (y + d_skip * x).astype(y_ref.dtype)
 
     # state update: state = exp(seg_Q) * state + sum_j w_j B_j x_j^T
-    decay_to_end = jnp.exp(seg[-1] - seg)          # (Q,)
-    wb = b * (decay_to_end * dt)[:, None]          # (Q, N)
+    seg_end = jnp.sum(dta)                         # scalar: seg at Q-1
+    wb = b * (jnp.exp(seg_end - seg_c) * dt_c)     # (Q, N)
     ds = jax.lax.dot_general(wb, x, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (N, P)
-    state_ref[...] = state_ref[...] * jnp.exp(seg[-1]) + ds
+    state_ref[...] = state_ref[...] * jnp.exp(seg_end) + ds
 
     if fs_ref is not None:
         @pl.when(ci == nc - 1)
@@ -131,7 +145,8 @@ def ssd(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray, b: jnp.ndarray,
 
     # (B, H, nc, Q, ...) layouts so the last two dims are MXU tiles
     xt = jnp.moveaxis(x, 2, 1).reshape(bsz, h, nc, q, p)
-    dtt = jnp.moveaxis(dt, 2, 1).reshape(bsz, h, nc, q)
+    # (.., 1, Q): a (1, Q) last-two-dim block is a legal TPU tile
+    dtt = jnp.moveaxis(dt, 2, 1).reshape(bsz, h, nc, 1, q)
     bt = jnp.moveaxis(b, 2, 1).reshape(bsz, g, nc, q, n)
     ct = jnp.moveaxis(c, 2, 1).reshape(bsz, g, nc, q, n)
     a = -jnp.exp(a_log.astype(jnp.float32))        # (H,)
@@ -153,11 +168,11 @@ def ssd(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray, b: jnp.ndarray,
         grid=(bsz, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, q, p), lambda bb, hh, cc: (bb, hh, cc, 0, 0)),
-            pl.BlockSpec((1, 1, 1, q), lambda bb, hh, cc: (bb, hh, cc, 0)),
-            pl.BlockSpec((1,), lambda bb, hh, cc: (hh,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda bb, hh, cc: (hh,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, 1, 1, q),
+                         lambda bb, hh, cc: (bb, hh, cc, 0, 0)),
+            # whole (H,) vectors in SMEM, indexed by head in the body
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, q, n),
                          lambda bb, hh, cc: (bb, hh // hpg, cc, 0, 0)),
             pl.BlockSpec((1, 1, 1, q, n),
@@ -166,7 +181,7 @@ def ssd(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray, b: jnp.ndarray,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=kernels_pkg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xt, dtt, a, d, bt, ct)

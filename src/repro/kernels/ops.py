@@ -110,18 +110,57 @@ def gemm_impl(a: jnp.ndarray, b: jnp.ndarray, d: Optional[jnp.ndarray] = None,
         return ref_ops.gemm_ref(a, b, d, acc_dtype=cfg.acc_jnp,
                                 out_dtype=cfg.output_jnp, shift=shift,
                                 activation=activation)
-    plan = plan or _resolve_plan(cfg, m, n, k, dataflow=dataflow,
-                                 has_bias=d is not None)
-    ap = _pad2(a, plan.m, plan.k)
-    bp = _pad2(b, plan.k, plan.n)
-    dp = None
-    if d is not None:
-        dp = _pad2(jnp.broadcast_to(d, (m, n)).astype(cfg.acc_jnp),
-                   plan.m, plan.n)
-    out = gemm_kernel.gemm(ap, bp, dp, plan, cfg, dataflow=dataflow,
-                           shift=shift, activation=activation,
-                           interpret=(backend == "interpret"))
-    return out[:m, :n]
+
+    def run(a, b, d, plan=None):
+        m, k = a.shape
+        n = b.shape[1]
+        plan = plan or _resolve_plan(cfg, m, n, k, dataflow=dataflow,
+                                     has_bias=d is not None)
+        ap = _pad2(a, plan.m, plan.k)
+        bp = _pad2(b, plan.k, plan.n)
+        dp = None
+        if d is not None:
+            dp = _pad2(jnp.broadcast_to(d, (m, n)).astype(cfg.acc_jnp),
+                       plan.m, plan.n)
+        out = gemm_kernel.gemm(ap, bp, dp, plan, cfg, dataflow=dataflow,
+                               shift=shift, activation=activation,
+                               interpret=(backend == "interpret"))
+        return out[:m, :n]
+
+    if shift == 0 and activation is Activation.NONE and \
+            jnp.issubdtype(cfg.input_jnp, jnp.floating):
+        return _linear_gemm(run, a, b, d, plan)
+    return run(a, b, d, plan)
+
+
+def _linear_gemm(run, a, b, d, plan):
+    """``run(a, b, d)`` (the float engine datapath, linear in each operand)
+    with a VJP built from the same kernel: pallas_call itself has no
+    transpose, and the trainer differentiates every projection.
+
+      dA = dC @ B^T    dB = A^T @ dC    dD = dC summed over D's broadcast
+    """
+
+    @jax.custom_vjp
+    def f(a, b, d):
+        return run(a, b, d, plan)
+
+    def fwd(a, b, d):
+        return run(a, b, d, plan), (a, b, d)
+
+    def bwd(res, dc):
+        a, b, d = res
+        da = run(dc, b.T, None).astype(a.dtype)
+        db = run(a.T, dc, None).astype(b.dtype)
+        dd = None
+        if d is not None:
+            dd = dc if d.size != dc.shape[1] else \
+                jnp.sum(dc.astype(jnp.float32), axis=0).reshape(d.shape)
+            dd = dd.astype(d.dtype)
+        return da, db, dd
+
+    f.defvjp(fwd, bwd)
+    return f(a, b, d)
 
 
 def matmul_impl(a: jnp.ndarray, b: jnp.ndarray, *, cfg: GemminiConfig,

@@ -15,21 +15,15 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on jax >= 0.6; older jax treats
-    every axis as Auto already."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    """A device mesh whose axes are all ``Auto``: GSPMD partitions what
+    the sharding annotations leave open."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def activate_mesh(mesh):
-    """Context manager installing ``mesh``: ``jax.set_mesh`` on new jax; on
-    0.4.x the Mesh object itself is the resource-env context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -28,7 +28,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def split_stages(stacked_params: Any, n_stages: int) -> Any:
@@ -88,9 +87,9 @@ def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
     # other mesh axes: params/x replicated from PP's point of view (their
     # sharding is handled by the surrounding pjit partitioner)
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(pspec, P()), out_specs=P(),
-                     check_rep=False)(stage_params, x_microbatches)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(pspec, P()), out_specs=P(),
+                         check_vma=False)(stage_params, x_microbatches)
 
 
 def pipeline_loss_fn(stage_fn, embed_fn, unembed_loss_fn):

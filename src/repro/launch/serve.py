@@ -20,6 +20,8 @@ import numpy as np
 
 from repro import configs
 from repro.core import flags
+from repro.core.generator import default_engine_backend
+from repro.launch import platform
 from repro.serving import ServingEngine
 
 
@@ -190,6 +192,9 @@ def main(argv=None):
                          "dispatch) and print achieved-vs-roofline "
                          "utilization per kernel bucket")
     args = ap.parse_args(argv)
+    platform.use_compile_cache()
+    backend = args.backend or default_engine_backend()
+    print(f"[serve] {platform.device_banner(backend)}")
     # Always re-set: set_flag validates, so a typo'd $GEMMINI_TUNE fails at
     # startup instead of (maybe never) at the first plan resolution.
     flags.set_flag("tune_mode", args.tune if args.tune is not None
@@ -217,7 +222,7 @@ def main(argv=None):
                         policy=args.policy, max_slots=args.slots,
                         page_size=args.page_size,
                         prefill_chunk=args.prefill_chunk,
-                        backend=args.backend,
+                        backend=backend,
                         admission_policy=args.admission,
                         faults=args.faults,
                         enforce_deadlines=args.enforce_deadlines,

@@ -24,6 +24,7 @@ import functools
 import os
 import sys
 import time
+from typing import Any
 
 LHS_FLAGS = (
     "--xla_tpu_enable_latency_hiding_scheduler=true "
@@ -54,6 +55,7 @@ from repro.core.generator import (default_engine_backend,      # noqa: E402
                                   elaborate)
 from repro.data import SyntheticLM, SyntheticLMConfig, \
     make_global_batch                                           # noqa: E402
+from repro.launch import platform                               # noqa: E402
 from repro.launch import sharding as shd                        # noqa: E402
 from repro.launch.mesh import activate_mesh, make_mesh          # noqa: E402
 from repro.launch import steps as steps_lib                     # noqa: E402
@@ -78,6 +80,7 @@ class RunResult:
     final_loss: float
     losses: list
     straggler_steps: int
+    state: Any = None               # the final TrainState, on the mesh
 
 
 def train_once(args, model_cfg, pods: int) -> RunResult:
@@ -89,7 +92,7 @@ def train_once(args, model_cfg, pods: int) -> RunResult:
     # stays on the GSPMD-partitioned plan-free reference.
     engine = elaborate(GemminiConfig(input_dtype="bf16", acc_dtype="fp32",
                                      output_dtype="bf16"),
-                       default_engine_backend()
+                       args.backend or default_engine_backend()
                        ).with_mesh(mesh, axis=shd.data_axis(mesh))
     opt_cfg = adamw.AdamWConfig(lr=args.lr)
     batch, seq = args.batch, args.seq
@@ -191,7 +194,7 @@ def train_once(args, model_cfg, pods: int) -> RunResult:
             if mgr is not None:
                 mgr.save(step, state, extra_meta={"arch": model_cfg.name})
             return RunResult(step, losses[-1] if losses else float("nan"),
-                             losses, stragglers)
+                             losses, stragglers, state)
         finally:
             # Flush any in-flight async checkpoint before this attempt
             # unwinds: an in-process restart (run_with_restarts) builds a
@@ -201,7 +204,7 @@ def train_once(args, model_cfg, pods: int) -> RunResult:
                 mgr.wait()
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -223,7 +226,18 @@ def main(argv=None):
                     help="enable latency-hiding-scheduler XLA flags")
     ap.add_argument("--tune", choices=flags.TUNE_MODES, default=None,
                     help="tile-plan autotuning mode (default: $GEMMINI_TUNE)")
-    args = ap.parse_args(argv)
+    ap.add_argument("--backend", choices=("xla", "pallas", "interpret"),
+                    default="",
+                    help="engine ExecutionContext backend (default: pallas "
+                         "on TPU hosts, xla elsewhere)")
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    platform.use_compile_cache()
+    print(f"[train] "
+          f"{platform.device_banner(args.backend or default_engine_backend())}")
     # Always re-set: set_flag validates, so a typo'd $GEMMINI_TUNE fails at
     # startup instead of (maybe never) at the first plan resolution.
     flags.set_flag("tune_mode", args.tune if args.tune is not None

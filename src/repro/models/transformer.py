@@ -286,9 +286,10 @@ def init_params(key, cfg: ModelConfig) -> Params:
     else:
         embed = layers.embed_init(ks[0], cfg.vocab, cfg.d_model,
                                   dtype=cfg.dtype)
-    # stacked per-layer params: tree_map over per-layer inits
-    per_layer = [_block_init(ks[4 + i], cfg) for i in range(cfg.n_layers)]
-    blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer)
+    # stacked per-layer params, built stacked (vmap over the layer keys):
+    # a list of per-layer trees plus their stack would hold the blocks
+    # twice, which at published widths does not fit one chip
+    blocks = jax.vmap(lambda k: _block_init(k, cfg))(ks[4:])
     p: Params = {"embed": embed, "blocks": blocks,
                  "final_norm": layers.rmsnorm_init(cfg.d_model)}
     if cfg.n_codebooks > 1:

@@ -48,22 +48,28 @@ def kernel_table(events: Iterable[Dict[str, Any]]) -> List[str]:
         cell = agg.setdefault(key, {"calls": 0, "total": 0.0,
                                     "best": float("inf"),
                                     "flops": float(args.get("flops") or 0.0),
-                                    "bytes": float(args.get("bytes") or 0.0)})
+                                    "bytes": float(args.get("bytes") or 0.0),
+                                    "kind": args.get("device_kind")})
         cell["calls"] += 1
         cell["total"] += float(ev.get("dur", 0.0))
         cell["best"] = min(cell["best"], float(ev.get("dur", 0.0)))
     if not agg:
         return []
-    from repro.analysis.roofline import HBM_BW, PEAK_FLOPS_BF16
+    from repro.analysis.roofline import PEAKS
     lines = [f"{'op':<24} {'contract':<24} {'calls':>6} {'best':>10} "
              f"{'comp%':>7} {'mem%':>7}"]
     for (name, contract, _sig), cell in sorted(
             agg.items(), key=lambda kv: kv[1]["total"], reverse=True):
         best_s = cell["best"] / 1e6
-        cu = (cell["flops"] / best_s / PEAK_FLOPS_BF16 * 100) if best_s else 0
-        mu = (cell["bytes"] / best_s / HBM_BW * 100) if best_s else 0
+        # Shares only against the peaks of the chip that ran the op.
+        chip = PEAKS.get(cell["kind"])
+        if chip is None or not best_s:
+            cu = mu = "--"
+        else:
+            cu = f"{cell['flops'] / best_s / chip.flops_bf16 * 100:.2f}"
+            mu = f"{cell['bytes'] / best_s / chip.hbm_bw * 100:.2f}"
         lines.append(f"{name:<24} {str(contract):<24} {cell['calls']:>6.0f} "
-                     f"{_fmt_ms(cell['best']):>10} {cu:>7.2f} {mu:>7.2f}")
+                     f"{_fmt_ms(cell['best']):>10} {cu:>7} {mu:>7}")
     return lines
 
 

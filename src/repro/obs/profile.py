@@ -6,12 +6,14 @@ When a :class:`Profiler` is installed (``GEMMINI_PROFILE=1`` env,
 timed with a blocking ``jax.block_until_ready`` sync and recorded into a
 per-(op, shape-signature) bucket, joined with the op's
 `KernelContract`-derived FLOPs/bytes (:mod:`repro.obs.kernel_costs`).
-Dividing by `analysis/roofline`'s per-chip peaks gives achieved
+Dividing by the published peaks of the chip that ran the ops
+(`analysis/roofline.PEAKS`, keyed by ``device_kind``) gives achieved
 compute/memory utilization per kernel instantiation — the software
-analog of the paper's hardware counters.
+analog of the paper's hardware counters. A device without published
+peaks (the CPU) gets timings and no utilization.
 
-Profiling applies only to EAGER dispatches (the same
-``trace_state_clean`` rule the fault injector follows): a timer inside a
+Profiling applies only to EAGER dispatches (the same trace-state rule
+the fault injector follows): a timer inside a
 jit trace would measure tracing, not execution, and the blocking sync
 would serialize the compiled pipeline.  Ops dispatched inside a jitted
 engine step are invisible here — profile with an eager/interpret
@@ -26,7 +28,7 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from repro.analysis.roofline import HBM_BW, PEAK_FLOPS_BF16, PEAK_OPS_INT8
+from repro.analysis import roofline
 from repro.obs import kernel_costs
 
 ENV_VAR = "GEMMINI_PROFILE"
@@ -72,27 +74,28 @@ class OpBucket:
         self.min_s = min(self.min_s, dt_s)
         self.max_s = max(self.max_s, dt_s)
 
-    @property
-    def peak_flops(self) -> float:
-        return PEAK_OPS_INT8 if self.arith == "int" else PEAK_FLOPS_BF16
-
-    def utilization(self) -> Dict[str, Optional[float]]:
+    def utilization(self, peaks: Optional[roofline.ChipPeaks]
+                    ) -> Dict[str, Optional[float]]:
         """Achieved-vs-roofline fractions from the bucket's BEST call
         (min_s): warmup/compile noise inflates means, and the roofline
-        question is what the kernel can sustain."""
-        if not self.calls or self.min_s == float("inf"):
-            return {"compute": None, "memory": None, "bound": None}
+        question is what the kernel can sustain. ``peaks`` is the chip the
+        timings came from; None (a device with no published peaks, such as
+        the CPU) leaves every fraction unmeasured."""
+        none = {"compute": None, "memory": None, "bound": None}
+        if peaks is None or not self.calls or self.min_s == float("inf"):
+            return none
         if self.flops <= 0 and self.bytes <= 0:
-            return {"compute": None, "memory": None, "bound": None}
-        cu = (self.flops / self.min_s) / self.peak_flops
-        mu = (self.bytes / self.min_s) / HBM_BW
-        t_c = self.flops / self.peak_flops
-        t_m = self.bytes / HBM_BW
+            return none
+        peak = peaks.ops_int8 if self.arith == "int" else peaks.flops_bf16
+        cu = (self.flops / self.min_s) / peak
+        mu = (self.bytes / self.min_s) / peaks.hbm_bw
+        t_c = self.flops / peak
+        t_m = self.bytes / peaks.hbm_bw
         return {"compute": cu, "memory": mu,
                 "bound": "compute" if t_c >= t_m else "memory"}
 
-    def row(self) -> Dict[str, Any]:
-        util = self.utilization()
+    def row(self, peaks: Optional[roofline.ChipPeaks]) -> Dict[str, Any]:
+        util = self.utilization(peaks)
         return {
             "op": self.op, "sig": self.sig, "contract": self.contract,
             "calls": self.calls, "total_s": self.total_s,
@@ -112,9 +115,14 @@ class Profiler:
     """
 
     def __init__(self, *, clock=time.perf_counter, tracer=None) -> None:
+        import jax
         self.clock = clock
         self.tracer = tracer
         self.buckets: Dict[Tuple[str, str], OpBucket] = {}
+        self.device_kind = jax.devices()[0].device_kind
+        # Utilization divides by the peaks of the chip that ran the ops; a
+        # device with no published peaks reports none.
+        self.peaks = roofline.PEAKS.get(self.device_kind)
 
     def bucket(self, op: str, args: Tuple, kw: Dict[str, Any], cfg
                ) -> OpBucket:
@@ -138,12 +146,13 @@ class Profiler:
             self.tracer.complete(
                 bucket.op, t0, t1, cat="kernel", tid=otrace.TID_PROFILE,
                 contract=bucket.contract, flops=bucket.flops,
-                bytes=bucket.bytes, sig=bucket.sig)
+                bytes=bucket.bytes, sig=bucket.sig,
+                device_kind=self.device_kind)
 
     # -------------------------------------------------------------- report
 
     def table(self, *, by: str = "total_s") -> List[Dict[str, Any]]:
-        rows = [b.row() for b in self.buckets.values()]
+        rows = [b.row(self.peaks) for b in self.buckets.values()]
         rows.sort(key=lambda r: r.get(by) or 0.0, reverse=True)
         return rows
 

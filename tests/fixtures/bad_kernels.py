@@ -42,7 +42,7 @@ def unregistered_launch(a):
         out_specs=pl.BlockSpec((128, 128), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((512, 128), jnp.float32),
         input_output_aliases={0: 0},
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(a)
 

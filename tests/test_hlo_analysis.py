@@ -34,8 +34,6 @@ def test_scan_flops_scaled_by_trip_count():
 
     # XLA's builtin undercounts the scan ~10x -- the bug we fix:
     ca = cs.cost_analysis()
-    if isinstance(ca, list):      # jax < 0.6 returns one dict per device
-        ca = ca[0]
     assert ca["flops"] < 0.2 * analytic_dots
     # our analyzer agrees with both the unrolled version and the math:
     assert abs(rs.flops - ru.flops) / ru.flops < 0.01
@@ -127,17 +125,27 @@ def test_parse_module_structure():
 
 
 def test_roofline_fraction_math():
+    v5e = roofline.peaks("TPU v5 lite")
     rl = roofline.Roofline(
         arch="x", shape="train_4k", mesh="16x16",
         flops=1e12, hbm_bytes=1e11, coll_bytes=1e9,
         coll_breakdown={}, per_device_hbm_peak=1e10,
         model_flops=2.56e14, n_chips=256)
     # terms
-    assert abs(rl.t_compute - 1e12 / roofline.PEAK_FLOPS_BF16) < 1e-12
-    assert abs(rl.t_memory - 1e11 / roofline.HBM_BW) < 1e-12
+    assert abs(rl.t_compute - 1e12 / v5e.flops_bf16) < 1e-12
+    assert abs(rl.t_memory - 1e11 / v5e.hbm_bw) < 1e-12
     assert rl.bottleneck == "memory"
-    ideal = 2.56e14 / 256 / roofline.PEAK_FLOPS_BF16
+    ideal = 2.56e14 / 256 / v5e.flops_bf16
     assert abs(rl.roofline_fraction - ideal / rl.t_bound) < 1e-9
+
+
+def test_peaks_table_is_keyed_by_device_kind():
+    v5e = roofline.peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.ops_int8, v5e.hbm_bw) == (197e12, 393e12,
+                                                          819e9)
+    # a device without published peaks is an error, not a default
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.peaks("cpu")
 
 
 # ---------------------------------------------------------------------------

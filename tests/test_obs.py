@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis import roofline
 from repro.core.config import GemminiConfig
 from repro.core.context import ExecutionContext
 from repro.models import transformer as tf
@@ -284,11 +285,19 @@ def test_profiler_covers_all_kernel_families():
     want = {"gemm", "matmul", "conv2d", "flash_attention",
             "paged_attention", "paged_prefill_attention", "ssd"}
     assert want <= set(rows)
+    # The CPU has no published peaks: its timings get no utilization.
+    assert prof.peaks is None
     for op in want:
         r = rows[op]
         assert r["contract"], op
         assert r["flops"] > 0 and r["bytes"] > 0, op
         assert r["calls"] == 1 and r["min_s"] is not None, op
+        assert r["compute_util"] is None and r["bound"] is None, op
+    # Against a chip's peaks every bucket gets a utilization verdict.
+    prof.peaks = roofline.peaks("TPU v5 lite")
+    rows = {r["op"]: r for r in prof.snapshot()}
+    for op in want:
+        r = rows[op]
         assert r["compute_util"] is not None and r["compute_util"] >= 0, op
         assert r["bound"] in ("compute", "memory"), op
     # contract-derived FLOPs are exact for known shapes

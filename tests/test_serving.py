@@ -762,13 +762,18 @@ def test_chunked_eviction_mid_prefill_recompute(rng):
 def test_chunked_engine_interpret_backend(rng):
     """backend="interpret" drives the chunked-prefill Pallas kernel
     (block-table gather) end-to-end; greedy tokens agree with the xla
-    engine."""
+    engine. The engine cfg is pinned f32 end-to-end, as in the SSM twin
+    below: the xla backend's float projection shortcut and the kernel
+    backends' engine datapath round differently in bf16, and greedy
+    tokens of random weights flip on that rounding."""
+    f32 = GemminiConfig(input_dtype="fp32", acc_dtype="fp32",
+                        output_dtype="fp32")
     prompts = [rng.integers(0, 64, (n,)).astype(np.int32) for n in (13, 4)]
     reps = {}
     for backend in ("xla", "interpret"):
         eng = ServingEngine(_TINY, max_slots=2, max_context=32, page_size=8,
                             n_pages=8, temperature=0.0, seed=0,
-                            backend=backend, prefill_chunk=8)
+                            backend=backend, prefill_chunk=8, engine_cfg=f32)
         for p in prompts:
             eng.submit(p, 3)
         reps[backend] = [np.asarray(r["tokens"])

@@ -245,59 +245,16 @@ class ExecutionContext:
                 f"ExecutionContext has no op {name!r}; registered ops: "
                 f"{registered_ops()}")
         fn = functools.partial(_OPS[name], self)
-        prof = _profiler()
-        if prof is not None:
-            # Innermost wrap: timing excludes the fault injector's
-            # host-side bookkeeping (and a poisoned output is still the
-            # op the bucket timed).
-            fn = _profiled_op(name, fn, prof, self)
         inj = _fault_injector()
         return fn if inj is None else _faulted_op(name, fn, inj)
 
 
 def _tracing() -> bool:
-    """True while JAX traces (jit, grad, scan bodies): the op hooks below
-    then pass through, since their host-side work would be baked into the
+    """True while JAX traces (jit, grad, scan bodies): the fault hook below
+    then passes through, since their host-side work would be baked into the
     compiled program or would block on tracers."""
     import jax
     return not jax.core.trace_ctx.is_top_level()
-
-
-def _profiler():
-    """The process-global kernel profiler, if one is installed (see
-    :mod:`repro.obs.profile`). Lazy import, same layering rule as the
-    fault injector below; the common case (no profiling) costs one None
-    check per dispatch."""
-    try:
-        from repro.obs import profile
-    except ImportError:                       # pragma: no cover - stub envs
-        return None
-    return profile.active()
-
-
-def _profiled_op(name: str, fn: Callable, prof, ctx) -> Callable:
-    """Wrap one op dispatch with blocking-sync timing into the profiler's
-    (op, shape-signature) bucket, joined with the op's KernelContract
-    FLOPs/bytes (repro.obs.kernel_costs).
-
-    EAGER calls only — under a jit trace the wrapper is a pass-through:
-    a timer at trace time would measure tracing, and the blocking sync
-    would serialize the compiled pipeline (the exact rule _faulted_op
-    follows)."""
-
-    @functools.wraps(fn)
-    def wrapped(*args, **kw):
-        if _tracing():
-            return fn(*args, **kw)
-        import jax
-        bucket = prof.bucket(name, args, kw, ctx.cfg)
-        t0 = prof.clock()
-        out = fn(*args, **kw)
-        jax.block_until_ready(out)
-        prof.record(bucket, t0, prof.clock())
-        return out
-
-    return wrapped
 
 
 def _fault_injector():

@@ -187,10 +187,11 @@ def main(argv=None):
                          "(default: TRACE_serve.json; load in "
                          "chrome://tracing or ui.perfetto.dev, or summarize "
                          "with python -m repro.obs PATH)")
-    ap.add_argument("--profile", action="store_true",
-                    help="time every ExecutionContext op (blocking sync per "
-                         "dispatch) and print achieved-vs-roofline "
-                         "utilization per kernel bucket")
+    ap.add_argument("--profile", default="", metavar="DIR",
+                    help="record a jax.profiler session over the serving "
+                         "run into DIR: an .xplane.pb with the device ops "
+                         "and the engine's phase spans on one clock "
+                         "(open in TensorBoard's profiler or Perfetto)")
     args = ap.parse_args(argv)
     platform.use_compile_cache()
     backend = args.backend or default_engine_backend()
@@ -200,41 +201,29 @@ def main(argv=None):
     flags.set_flag("tune_mode", args.tune if args.tune is not None
                    else flags.get("tune_mode"))
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
-    profiler = None
     import contextlib
     run_ctx = contextlib.nullcontext()
     if args.profile:
-        from repro.obs import profile as oprofile
-        profiler = oprofile.Profiler()
-        oprofile.install(profiler)
-        # Per-op timing happens at the ExecutionContext dispatch boundary,
-        # which the engine's jitted step functions would trace through
-        # (one opaque XLA call, no per-op boundaries). disable_jit makes
-        # every dispatch eager -- slower, but that's what opt-in profiling
-        # is for, and the op stream is identical.
         import jax
-        run_ctx = jax.disable_jit()
-        print("[serve] profiling: per-op sync timing (jit disabled)")
-    try:
-        with run_ctx:
-            out = serve(cfg, batch=args.batch, prompt_len=args.prompt_len,
-                        gen_len=args.gen, temperature=args.temperature,
-                        policy=args.policy, max_slots=args.slots,
-                        page_size=args.page_size,
-                        prefill_chunk=args.prefill_chunk,
-                        backend=backend,
-                        admission_policy=args.admission,
-                        faults=args.faults,
-                        enforce_deadlines=args.enforce_deadlines,
-                        deadline_s=args.deadline,
-                        trace=True if args.trace else None,
-                        kv_offload=args.kv_offload,
-                        prefix_cache=args.prefix_cache,
-                        host_pool_pages=args.host_pool_pages)
-    finally:
-        if profiler is not None:
-            from repro.obs import profile as oprofile
-            oprofile.deactivate()
+        run_ctx = jax.profiler.trace(args.profile)
+    with run_ctx:
+        out = serve(cfg, batch=args.batch, prompt_len=args.prompt_len,
+                    gen_len=args.gen, temperature=args.temperature,
+                    policy=args.policy, max_slots=args.slots,
+                    page_size=args.page_size,
+                    prefill_chunk=args.prefill_chunk,
+                    backend=backend,
+                    admission_policy=args.admission,
+                    faults=args.faults,
+                    enforce_deadlines=args.enforce_deadlines,
+                    deadline_s=args.deadline,
+                    trace=True if args.trace else None,
+                    kv_offload=args.kv_offload,
+                    prefix_cache=args.prefix_cache,
+                    host_pool_pages=args.host_pool_pages)
+    if args.profile:
+        print(f"[serve] profile: {args.profile} (device ops and engine.* "
+              f"spans; read with jax.profiler.ProfileData.from_file)")
     s = out["report"]["summary"]
 
     def ms(v):
@@ -272,8 +261,6 @@ def main(argv=None):
         print(f"[serve] trace: {len(tracer.events)} events "
               f"({tracer.dropped} dropped) -> {args.trace_out} "
               f"(summarize: python -m repro.obs {args.trace_out})")
-    if profiler is not None:
-        print(profiler.report())
     return out
 
 

@@ -1,26 +1,26 @@
-"""Observability substrate: span tracing, metrics, kernel perf counters.
+"""Observability substrate: span tracing, metrics, phase spans.
 
-Three cooperating pieces (docs/observability.md):
+Two cooperating pieces (docs/observability.md):
 
 - :mod:`repro.obs.trace` — ring-buffered span tracer (request lifecycle,
   engine step phases, allocator/tuner/fault events) with Chrome-trace /
-  JSONL export.  Off by default; ``GEMMINI_TRACE`` /
-  ``ServingEngine(trace=)`` / ``serve --trace`` enable it.
+  JSONL export, off by default (``GEMMINI_TRACE`` /
+  ``ServingEngine(trace=)`` / ``serve --trace`` enable it); and
+  :class:`~repro.obs.trace.Spans`, the serving engine's always-on phase
+  spans, which land as ``jax.profiler`` annotations on the device trace's
+  clock and as timed registry observations.
 - :mod:`repro.obs.metrics` — labelled counters/gauges/histograms; the
   one schema behind ``engine.summarize()`` and BENCH_serving rows.
-- :mod:`repro.obs.profile` + :mod:`repro.obs.kernel_costs` — opt-in
-  per-op timing at the `ExecutionContext` boundary joined with
-  `KernelContract` FLOPs/bytes into achieved-vs-roofline utilization
-  (``GEMMINI_PROFILE`` / ``serve --profile``).
 
-``python -m repro.obs <trace.json>`` summarizes an exported trace.
+``python -m repro.obs <trace.json>`` summarizes an exported trace;
+``serve --profile DIR`` writes a profiler session with the device ops and
+the phase spans.
 """
 
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.profile import Profiler
-from repro.obs.trace import Tracer, req_tid, validate_chrome
+from repro.obs.trace import Spans, Tracer, req_tid, validate_chrome
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "Profiler", "Tracer", "req_tid", "validate_chrome",
+    "Spans", "Tracer", "req_tid", "validate_chrome",
 ]

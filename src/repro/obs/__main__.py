@@ -1,11 +1,9 @@
 """Trace inspector CLI: ``python -m repro.obs <trace> [--check] [--top K]``.
 
 Summarizes an exported trace (Chrome-trace JSON or JSONL event log):
-top-k spans by self time, the kernel utilization table (when the trace
-carries profiled ``cat="kernel"`` spans), and a per-request lifecycle
-timeline.  ``--check`` validates the Chrome-trace schema and exits
-non-zero on any violation — CI runs it as a gate on the serve smoke's
-trace artifact.
+top-k spans by total time and a per-request lifecycle timeline.
+``--check`` validates the Chrome-trace schema and exits non-zero on any
+violation — CI runs it as a gate on the serve smoke's trace artifact.
 """
 
 from __future__ import annotations
@@ -35,41 +33,6 @@ def top_spans(events: Iterable[Dict[str, Any]], k: int) -> List[str]:
     lines = [f"{'span':<36} {'cat':<10} {'count':>7} {'total':>12}"]
     for (cat, name), (count, total) in ranked:
         lines.append(f"{name:<36} {cat:<10} {count:>7} {_fmt_ms(total):>12}")
-    return lines
-
-
-def kernel_table(events: Iterable[Dict[str, Any]]) -> List[str]:
-    agg: Dict[tuple, Dict[str, float]] = {}
-    for ev in events:
-        if ev.get("ph") != "X" or ev.get("cat") != "kernel":
-            continue
-        args = ev.get("args") or {}
-        key = (ev["name"], args.get("contract"), args.get("sig"))
-        cell = agg.setdefault(key, {"calls": 0, "total": 0.0,
-                                    "best": float("inf"),
-                                    "flops": float(args.get("flops") or 0.0),
-                                    "bytes": float(args.get("bytes") or 0.0),
-                                    "kind": args.get("device_kind")})
-        cell["calls"] += 1
-        cell["total"] += float(ev.get("dur", 0.0))
-        cell["best"] = min(cell["best"], float(ev.get("dur", 0.0)))
-    if not agg:
-        return []
-    from repro.analysis.roofline import PEAKS
-    lines = [f"{'op':<24} {'contract':<24} {'calls':>6} {'best':>10} "
-             f"{'comp%':>7} {'mem%':>7}"]
-    for (name, contract, _sig), cell in sorted(
-            agg.items(), key=lambda kv: kv[1]["total"], reverse=True):
-        best_s = cell["best"] / 1e6
-        # Shares only against the peaks of the chip that ran the op.
-        chip = PEAKS.get(cell["kind"])
-        if chip is None or not best_s:
-            cu = mu = "--"
-        else:
-            cu = f"{cell['flops'] / best_s / chip.flops_bf16 * 100:.2f}"
-            mu = f"{cell['bytes'] / best_s / chip.hbm_bw * 100:.2f}"
-        lines.append(f"{name:<24} {str(contract):<24} {cell['calls']:>6.0f} "
-                     f"{_fmt_ms(cell['best']):>10} {cu:>7} {mu:>7}")
     return lines
 
 
@@ -142,12 +105,6 @@ def main(argv=None) -> int:
     print("-- top spans by total time --")
     for line in top_spans(events, args.top):
         print(line)
-    kt = kernel_table(events)
-    if kt:
-        print()
-        print("-- kernel utilization (from profiled spans) --")
-        for line in kt:
-            print(line)
     tl = request_timeline(events)
     if tl:
         print()

@@ -60,31 +60,54 @@ class Gauge:
 
 @dataclass
 class Histogram:
-    """Reservoir of observations with exact percentiles.
+    """Bounded reservoir of ``(t, value)`` observations with exact
+    percentiles.
 
-    Bounded: keeps the most recent ``capacity`` samples plus running
-    count/sum so rates stay exact even after the window slides.
+    Keeps the most recent ``capacity`` observations plus running
+    count/sum, so rates stay exact even after the window slides. ``t`` is
+    the observation's time on the caller's clock (None when untimed): a
+    reader takes the observations of one time window with :meth:`window`.
+    The default capacity holds five observations per step of a 51-s window
+    at 1 ms per step; a decode step makes at most four of one phase span
+    (``engine.plan``).
     """
 
     name: str
     labels: Dict[str, Any]
-    capacity: int = 4096
+    capacity: int = 1 << 18
     count: int = 0
     sum: float = 0.0
-    samples: Deque[float] = field(default_factory=collections.deque, repr=False)
+    samples: Deque[Tuple[Optional[float], float]] = field(init=False,
+                                                          repr=False)
+    # the time of the newest timed observation the reservoir has dropped
+    dropped_t: Optional[float] = field(default=None, repr=False)
 
-    def observe(self, value: float) -> None:
+    def __post_init__(self) -> None:
+        self.samples = collections.deque(maxlen=self.capacity)
+
+    def observe(self, value: float, t: Optional[float] = None) -> None:
         self.count += 1
         self.sum += value
-        if len(self.samples) >= self.capacity:
-            self.samples.popleft()
-        self.samples.append(value)
+        if len(self.samples) == self.capacity and \
+                self.samples[0][0] is not None:
+            self.dropped_t = self.samples[0][0]
+        self.samples.append((t, value))
+
+    def window(self, t0: float,
+               t1: float) -> Optional[List[Tuple[float, float]]]:
+        """The timed observations with ``t0 <= t <= t1``; None when the
+        reservoir has dropped one at or after ``t0``, so a reader never
+        takes part of a window for the whole."""
+        if self.dropped_t is not None and self.dropped_t >= t0:
+            return None
+        return [(t, v) for t, v in self.samples
+                if t is not None and t0 <= t <= t1]
 
     def percentile(self, p: float) -> Optional[float]:
         """Exact percentile over the retained window; None when empty."""
         if not self.samples:
             return None
-        xs = sorted(self.samples)
+        xs = sorted(v for _, v in self.samples)
         rank = (p / 100.0) * (len(xs) - 1)
         lo = math.floor(rank)
         hi = math.ceil(rank)
@@ -138,6 +161,23 @@ class MetricsRegistry:
     def value(self, name: str) -> float:
         """Sum of a counter across every label set (0.0 if never touched)."""
         return sum(c.value for c in self._counters.values() if c.name == name)
+
+    def observations(self, name: str, t0: float = float("-inf"),
+                     t1: float = float("inf"),
+                     ) -> Optional[List[Tuple[float, float]]]:
+        """Timed ``(t, value)`` observations of histogram ``name`` with
+        ``t0 <= t <= t1``, pooled across label sets, in time order; None
+        when some label set has dropped observations of the window
+        (:meth:`Histogram.window`)."""
+        out: List[Tuple[float, float]] = []
+        for h in self._histograms.values():
+            if h.name == name:
+                w = h.window(t0, t1)
+                if w is None:
+                    return None
+                out += w
+        out.sort()
+        return out
 
     def gauge_peak(self, name: str) -> Optional[float]:
         peaks = [g.max for g in self._gauges.values()

@@ -1,12 +1,13 @@
-"""Ring-buffered span tracer with Chrome-trace / JSONL export.
+"""Ring-buffered span tracer with Chrome-trace / JSONL export, and the
+serving engine's host-loop phase spans.
 
 The tracer is the single event sink for the whole stack: the serving
 engine emits request-lifecycle and step-phase spans, the paged allocator
 emits alloc/extend/evict/defrag events, the tuner emits measurement
-spans, the fault injector emits fault-fire instants, and the kernel
-profiler emits per-op timing spans.  Everything lands in one bounded
-`collections.deque` ring, so an always-on tracer in a long-running
-server costs O(capacity) memory and a dict append per event.
+spans, and the fault injector emits fault-fire instants.  Everything
+lands in one bounded `collections.deque` ring, so an always-on tracer in
+a long-running server costs O(capacity) memory and a dict append per
+event.
 
 Tracing is **off by default**.  It activates through any of:
 
@@ -24,9 +25,15 @@ Event model (Chrome trace event format, ``ts``/``dur`` in microseconds):
 
 Track (tid) layout inside the single process (pid 0):
 engine step phases on ``TID_ENGINE``, allocator on ``TID_ALLOC``, tuner
-on ``TID_TUNER``, faults on ``TID_FAULT``, kernel profile spans on
-``TID_PROFILE``, and each request on ``REQ_TID_BASE + rid`` so Perfetto
-renders one lane per request lifecycle.
+on ``TID_TUNER``, faults on ``TID_FAULT``, and each request on
+``REQ_TID_BASE + rid`` so Perfetto renders one lane per request
+lifecycle.
+
+:class:`Spans` is the engine's phase-span path (``SPANS``). It is always
+on, whatever the ring: each span is a timed observation in the engine's
+metrics registry and, in the device-backed engine, a
+``jax.profiler.TraceAnnotation`` (so a profiler session puts it on the
+device trace's clock); the ring gets it only when tracing is on.
 """
 
 from __future__ import annotations
@@ -46,7 +53,6 @@ TID_ENGINE = 0
 TID_ALLOC = 1
 TID_TUNER = 2
 TID_FAULT = 3
-TID_PROFILE = 4
 REQ_TID_BASE = 1000
 
 _THREAD_NAMES = {
@@ -54,7 +60,6 @@ _THREAD_NAMES = {
     TID_ALLOC: "allocator",
     TID_TUNER: "tuner",
     TID_FAULT: "faults",
-    TID_PROFILE: "kernels",
 }
 
 DEFAULT_CAPACITY = 65536
@@ -180,6 +185,88 @@ class Tracer:
             for ev in self.events:
                 f.write(json.dumps(ev) + "\n")
         return path
+
+
+# ------------------------------------------------------------ phase spans
+
+# The serving engine's host-loop phases (docs/observability.md), children
+# of ``engine.step``. A benchmark's own host spans are named ``sampling``,
+# ``bench:*`` and ``step:*``; no phase may take such a name.
+SPANS = ("engine.step", "engine.plan", "engine.prep", "engine.dispatch",
+         "engine.wait", "engine.commit")
+
+
+class Spans:
+    """One span path into three sinks.
+
+    ``with spans("engine.plan"):`` records the phase
+
+    - when ``annotation`` is given (the device-backed engine passes
+      ``jax.profiler.TraceAnnotation``), as ``annotation(name, **args)``,
+      which a profiler session stamps on the device trace's clock; with no
+      session running it costs the annotation object;
+    - always in ``metrics``: histogram ``name`` (labelled by ``args``)
+      observes the duration in seconds at the span's start on ``clock``;
+    - on ``tracer``'s ring, when there is one, as a complete span on the
+      engine track with the enclosing span's name as ``parent``.
+    """
+
+    def __init__(self, metrics, clock: Callable[[], float],
+                 tracer: Optional[Tracer] = None,
+                 annotation: Optional[Callable[..., Any]] = None) -> None:
+        self.metrics = metrics
+        self.clock = clock
+        self.tracer = tracer
+        self.annotation = annotation
+        self._open: List[str] = []
+        self._hists: Dict[Any, Any] = {}
+
+    def __call__(self, name: str, **args: Any) -> "_Span":
+        return _Span(self, name, args)
+
+    def _histogram(self, name: str, args: Dict[str, Any]):
+        # Cached past the registry's label-sorting lookup: every span's
+        # exit runs on the engine's hot loop.
+        key = (name, tuple(args.items()))
+        h = self._hists.get(key)
+        if h is None:
+            h = self._hists[key] = self.metrics.histogram(name, **args)
+        return h
+
+
+class _Span:
+    __slots__ = ("_sink", "_name", "_args", "_ann", "_t0", "_parent")
+
+    def __init__(self, sink: Spans, name: str, args: Dict[str, Any]):
+        self._sink = sink
+        self._name = name
+        self._args = args
+
+    def __enter__(self) -> "_Span":
+        sink = self._sink
+        # The annotation opens first and closes last, so it holds the
+        # registry's interval.
+        self._ann = None
+        if sink.annotation is not None:
+            self._ann = sink.annotation(self._name, **self._args)
+            self._ann.__enter__()
+        self._parent = sink._open[-1] if sink._open else None
+        sink._open.append(self._name)
+        self._t0 = sink.clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        sink = self._sink
+        t1 = sink.clock()
+        sink._open.pop()
+        sink._histogram(self._name, self._args).observe(t1 - self._t0,
+                                                        self._t0)
+        if sink.tracer is not None:
+            sink.tracer.complete(self._name, self._t0, t1, cat="engine",
+                                 tid=TID_ENGINE, parent=self._parent,
+                                 **self._args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
 
 
 # ------------------------------------------------------------- validation

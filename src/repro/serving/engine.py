@@ -189,6 +189,11 @@ class EngineControlPlane:
         self.clock = clock or time.monotonic
         self.tracer = otrace.as_tracer(trace, clock=self.clock)
         self.metrics = MetricsRegistry()
+        # The host loop's phase spans (engine.step and its children),
+        # always on as registry observations; the device-backed engine
+        # adds profiler annotations, and the ring gets them only when
+        # tracing is on.
+        self.spans = otrace.Spans(self.metrics, self.clock, self.tracer)
         # Bail-out cap for run(): overridable so tests can force the hang
         # diagnostics without 100k iterations.
         self.max_run_iters = 100_000
@@ -422,14 +427,18 @@ class EngineControlPlane:
         logits means the model itself diverged -- that raises, because
         sampling from NaN logits would silently emit garbage tokens.
         """
-        self.observed_buckets.setdefault(which, set()).add(
-            self._bucket_key(which, args))
+        seen = self.observed_buckets.setdefault(which, set())
+        bucket = self._bucket_key(which, args)
+        new_bucket = bucket not in seen
+        seen.add(bucket)
         inj = self.faults
         for attempt in range(self.max_step_retries + 1):
             try:
                 if inj is not None:
                     inj.check_transient(site)
-                logits, state = self._dispatch(which, args)
+                with self.spans("engine.dispatch", which=which,
+                                new_bucket=new_bucket):
+                    logits, state = self._dispatch(which, args)
                 break
             except rfaults.TransientOpError:
                 self.metrics.counter("retries", site=site).inc()
@@ -442,18 +451,20 @@ class EngineControlPlane:
                     time.sleep(self.retry_backoff_s * (2 ** attempt))
         if inj is not None and logits is not None:
             logits = inj.poison(site, logits)
-        if self.nan_guard and logits is not None and \
-                not bool(np.isfinite(np.asarray(logits)).all()):
-            self.metrics.counter("fallbacks", site=site).inc()
-            if self.tracer is not None:
-                self.tracer.instant("fallback", cat="engine", site=site,
-                                    which=which)
-            self._quarantine(site)
-            logits, state = self._dispatch_fallback(which, args)
-            if not bool(np.isfinite(np.asarray(logits)).all()):
-                raise FloatingPointError(
-                    f"non-finite logits at {site!r} survived the XLA "
-                    f"fallback: model divergence, not a kernel fault")
+        if self.nan_guard and logits is not None:
+            with self.spans("engine.wait"):
+                finite = bool(np.isfinite(np.asarray(logits)).all())
+            if not finite:
+                self.metrics.counter("fallbacks", site=site).inc()
+                if self.tracer is not None:
+                    self.tracer.instant("fallback", cat="engine", site=site,
+                                        which=which)
+                self._quarantine(site)
+                logits, state = self._dispatch_fallback(which, args)
+                if not bool(np.isfinite(np.asarray(logits)).all()):
+                    raise FloatingPointError(
+                        f"non-finite logits at {site!r} survived the XLA "
+                        f"fallback: model divergence, not a kernel fault")
         return logits, state
 
     # -- execution (control skeletons over the compute hooks) --------------
@@ -471,21 +482,23 @@ class EngineControlPlane:
             return
         t0 = self.clock()
         tok = self._exec_chunk(w)
-        req.cache_len = w.true_end
-        req.n_chunks += 1
-        self.sched.note_committed(req)
-        if self.tracer is not None:
-            if w.first and w.last:
-                self.tracer.complete("prefill", t0, cat="request",
-                                     tid=otrace.req_tid(req.rid), slot=slot,
-                                     tokens=w.true_end)
-            else:
-                self.tracer.complete(
-                    f"prefill_chunk[{req.n_chunks - 1}]", t0, cat="request",
-                    tid=otrace.req_tid(req.rid), slot=slot, start=w.start,
-                    end=w.true_end, last=w.last)
-        if w.last:
-            self._record_token(req, tok, self.clock())
+        with self.spans("engine.commit"):
+            req.cache_len = w.true_end
+            req.n_chunks += 1
+            self.sched.note_committed(req)
+            if self.tracer is not None:
+                if w.first and w.last:
+                    self.tracer.complete("prefill", t0, cat="request",
+                                         tid=otrace.req_tid(req.rid),
+                                         slot=slot, tokens=w.true_end)
+                else:
+                    self.tracer.complete(
+                        f"prefill_chunk[{req.n_chunks - 1}]", t0,
+                        cat="request", tid=otrace.req_tid(req.rid),
+                        slot=slot, start=w.start, end=w.true_end,
+                        last=w.last)
+            if w.last:
+                self._record_token(req, tok, self.clock())
 
     def _do_decode(self) -> None:
         active_np = np.zeros((self.max_slots,), bool)
@@ -495,12 +508,13 @@ class EngineControlPlane:
             # partially-prefilled cache can never be touched.
             active_np[slot] = not req.prefilling
         last = self._exec_decode(active_np)
-        now = self.clock()
-        for slot, req in list(self.sched.running.items()):
-            if req.prefilling:
-                continue
-            req.cache_len += 1
-            self._record_token(req, last[slot], now)
+        with self.spans("engine.commit"):
+            now = self.clock()
+            for slot, req in list(self.sched.running.items()):
+                if req.prefilling:
+                    continue
+                req.cache_len += 1
+                self._record_token(req, last[slot], now)
 
     # The two step phases, exposed individually so the model checker can
     # interleave them as atomic actions; step() composes exactly these, so
@@ -510,24 +524,32 @@ class EngineControlPlane:
         """Admission-boundary phase: shed expired deadlines, execute the
         scheduler's prefill chunk queue, drain unservable rejections.
         Returns the number of chunks executed."""
-        self.sched.shed_expired()
-        ws = self.sched.prefill_schedule(admit_new=admit_new)
+        with self.spans("engine.plan"):
+            self.sched.shed_expired()
+            ws = self.sched.prefill_schedule(admit_new=admit_new)
         for w in ws:
             self._do_prefill_chunk(w)
-        for req in self.sched.rejected:
-            # Regrew past the arena while preempted: finish truncated.
-            self.sched.finish(req, truncated=True)
-        self.sched.rejected = []
+        if self.sched.rejected:
+            with self.spans("engine.plan"):
+                for req in self.sched.rejected:
+                    # Regrew past the arena while preempted: finish
+                    # truncated.
+                    self.sched.finish(req, truncated=True)
+                self.sched.rejected = []
         return len(ws)
 
     def control_decode(self) -> None:
         """Decode-boundary phase: ensure every running slot can take one
         more token (preempting by eviction under pressure), shed expired
         deadlines, decode one token per fully-prefilled running slot."""
-        new_pages, _evicted, _trunc = self.sched.ensure_decode_capacity()
+        with self.spans("engine.plan"):
+            new_pages, _evicted, _trunc = \
+                self.sched.ensure_decode_capacity()
         if new_pages:
-            self._sync_tables({slot for slot, _ in new_pages})
-        self.sched.shed_expired()
+            with self.spans("engine.prep"):
+                self._sync_tables({slot for slot, _ in new_pages})
+        with self.spans("engine.plan"):
+            self.sched.shed_expired()
         if any(not r.prefilling for r in self.sched.running.values()):
             self._do_decode()
 
@@ -542,28 +564,28 @@ class EngineControlPlane:
         the whole step, so the scheduler's can_admit-then-alloc protocol
         stays consistent, then released). With ``assert_invariants`` on
         (``GEMMINI_CHECK``), the allocator's ownership oracle runs at the
-        step boundary."""
-        t0 = self.clock()
-        inj = self.faults
-        held = 0
-        if inj is not None:
-            inj.straggle("step")
-            k = inj.arena_pressure()
-            if k:
-                held = self.alloc.hold_pages(k)
-        try:
-            admit_new = not (self.policy == "static" and self.sched.running)
-            self.control_prefill(admit_new=admit_new)
-            self.control_decode()
-        finally:
-            if held:
-                self.alloc.release_held()
-            if self.assert_invariants:
-                self.alloc.check()
-            self._step_gauges()
-            if self.tracer is not None:
-                self.tracer.complete("step", t0, cat="engine",
-                                     tid=otrace.TID_ENGINE)
+        step boundary. The step and its phases are ``engine.*`` spans
+        (:class:`repro.obs.trace.Spans`)."""
+        with self.spans("engine.step"):
+            inj = self.faults
+            held = 0
+            if inj is not None:
+                inj.straggle("step")
+                k = inj.arena_pressure()
+                if k:
+                    held = self.alloc.hold_pages(k)
+            try:
+                admit_new = not (self.policy == "static"
+                                 and self.sched.running)
+                self.control_prefill(admit_new=admit_new)
+                self.control_decode()
+            finally:
+                with self.spans("engine.commit"):
+                    if held:
+                        self.alloc.release_held()
+                    if self.assert_invariants:
+                        self.alloc.check()
+                    self._step_gauges()
 
     def run(self) -> Dict:
         """Drain the queue; returns {summary, requests} telemetry.
@@ -726,6 +748,8 @@ class ServingEngine(EngineControlPlane):
       tracer for THIS engine (request lifecycle, step phases, allocator
       events). Off costs one None check per emission site; the disabled
       path is bit-exact against PR-7 (a regression test holds it there).
+      The step's phase spans (``self.spans``: profiler annotations and
+      registry observations) are on whatever ``trace`` says.
     * ``clock`` -- the engine's one monotonic clock (default
       ``time.monotonic``): every TTFT/ITL/latency/step duration and
       every trace timestamp derives from it, and ``submit(deadline=)``
@@ -770,6 +794,9 @@ class ServingEngine(EngineControlPlane):
                          retry_backoff_s=retry_backoff_s,
                          assert_invariants=assert_invariants,
                          watchdog=watchdog, trace=trace, clock=clock)
+        # Each phase span is also a profiler annotation, on the clock of
+        # the device trace.
+        self.spans.annotation = jax.profiler.TraceAnnotation
         self.temperature = temperature
         self.max_context = max_context
         cfg = engine_cfg or GemminiConfig(input_dtype="bf16",
@@ -1057,62 +1084,55 @@ class ServingEngine(EngineControlPlane):
         req, slot = w.req, w.slot
         meta = self.model_cfg.n_meta_tokens
         prompt = req.serve_prompt()
-        if w.first and w.last:
-            toks = prompt
-            pad = self._bucket(len(prompt)) - len(prompt)
+        true_len = len(prompt) + meta
+        with self.spans("engine.prep"):
+            if w.first and w.last:
+                toks = prompt
+                pad = self._bucket(len(prompt)) - len(prompt)
+            else:
+                toks = prompt[max(0, w.start - meta): w.true_end - meta]
+                pad = w.padded_end - w.true_end
             if pad:
                 toks = np.pad(toks, ((0, pad),) + ((0, 0),)
                               * (toks.ndim - 1))
-            row = self._table_row(slot)
-            logits, self.state = self._run_guarded(
-                "prefill", "prefill",
-                (self.params, jnp.asarray(toks[None]), self.state,
-                 jnp.int32(slot), jnp.asarray(row)))
-            true_len = len(prompt) + meta
+            args = (self.params, jnp.asarray(toks[None]), self.state,
+                    jnp.int32(slot), jnp.asarray(self._table_row(slot)))
+            if not w.first:
+                # Static dead-key bound for the gather attention: the
+                # scheduler stamps each continuation chunk with the pages
+                # the whole (padded) prompt will ever occupy
+                # (PrefillChunk.kv_pages) -- table entries past it can
+                # never hold live keys and need not be contracted.
+                args += (jnp.int32(w.start), w.kv_pages or None)
+        if w.first:
+            site, which = "prefill", "prefill" if w.last else "prefill_nl"
+        else:
+            site, which = "chunk", "chunk" if w.last else "chunk_nl"
+        logits, self.state = self._run_guarded(site, which, args)
+        if not w.last:
+            return None
+        with self.spans("engine.prep"):
             self.state = self.state._replace(
                 lengths=self.state.lengths.at[slot].set(true_len))
             self._sync_tables([slot])
-            return self._sample(logits[0, true_len - 1])
-        toks = prompt[max(0, w.start - meta): w.true_end - meta]
-        pad = (w.padded_end - w.true_end)
-        if pad:
-            toks = np.pad(toks, ((0, pad),) + ((0, 0),) * (toks.ndim - 1))
-        row = self._table_row(slot)
-        if w.first:
-            which = "prefill" if w.last else "prefill_nl"
-            logits, self.state = self._run_guarded(
-                "prefill", which,
-                (self.params, jnp.asarray(toks[None]), self.state,
-                 jnp.int32(slot), jnp.asarray(row)))
-        else:
-            # Static dead-key bound for the gather attention: the scheduler
-            # stamps each continuation chunk with the pages the whole
-            # (padded) prompt will ever occupy (PrefillChunk.kv_pages) --
-            # table entries past it can never hold live keys and need not
-            # be contracted.
-            which = "chunk" if w.last else "chunk_nl"
-            logits, self.state = self._run_guarded(
-                "chunk", which,
-                (self.params, jnp.asarray(toks[None]), self.state,
-                 jnp.int32(slot), jnp.asarray(row), jnp.int32(w.start),
-                 w.kv_pages or None))
-        if not w.last:
-            return None
-        self._sync_tables([slot])
-        true_len = len(prompt) + meta
-        self.state = self.state._replace(
-            lengths=self.state.lengths.at[slot].set(true_len))
-        return self._sample(logits[0, (true_len - 1) - w.start])
+            rows = logits[0, (true_len - 1) - w.start]
+        with self.spans("engine.wait"):
+            return self._sample(rows)
 
     def _exec_decode(self, active_np: np.ndarray) -> np.ndarray:
-        toks = self._next_token[:, None] \
-            if self.model_cfg.n_codebooks == 1 \
-            else self._next_token[:, None, :]
-        logits, self.state = self._run_guarded(
-            "decode", "decode",
-            (self.params, jnp.asarray(toks), self.state,
-             jnp.asarray(active_np)))
-        return self._sample(logits[:, -1])
+        with self.spans("engine.prep"):
+            toks = self._next_token[:, None] \
+                if self.model_cfg.n_codebooks == 1 \
+                else self._next_token[:, None, :]
+            args = (self.params, jnp.asarray(toks), self.state,
+                    jnp.asarray(active_np))
+        logits, self.state = self._run_guarded("decode", "decode", args)
+        # The sampled rows' slice queues behind the step without waiting;
+        # the sampler then blocks until the device is done.
+        with self.spans("engine.prep"):
+            rows = logits[:, -1]
+        with self.spans("engine.wait"):
+            return self._sample(rows)
 
     # -- maintenance -------------------------------------------------------
     def defrag(self) -> None:

@@ -79,6 +79,7 @@ class Request:
     submitted_at: float = 0.0
     queued_since: float = 0.0             # start of the CURRENT queue wait
     admitted_seq: int = -1                # admission order (eviction key)
+    t_admitted: Optional[float] = None    # first admission into a slot
     t_first_token: Optional[float] = None
     t_last_token: Optional[float] = None
     t_finished: Optional[float] = None
@@ -240,7 +241,10 @@ class ContinuousScheduler:
                             tid=otrace.req_tid(req.rid), **args)
 
     def _note_admitted(self, req: Request) -> None:
-        """Close the request's queued span and count the admission."""
+        """Stamp the first admission, close the request's queued span and
+        count the admission."""
+        if req.t_admitted is None:
+            req.t_admitted = self.clock()
         self._count("admissions")
         if self.tracer is None:
             return
@@ -787,6 +791,9 @@ def summarize(requests: List[Request], wall_s: float) -> Dict[str, float]:
            if r.t_finished is not None]
     ttft = [r.t_first_token - r.submitted_at for r in done
             if r.t_first_token is not None]
+    # queue wait: submission to the first admission into a slot
+    wait = [r.t_admitted - r.submitted_at for r in requests
+            if r.t_admitted is not None]
     itl = [g for r in requests for g in r.itl_s]
     new_tokens = sum(r.n_generated for r in done)
     return {
@@ -798,6 +805,8 @@ def summarize(requests: List[Request], wall_s: float) -> Dict[str, float]:
         "p99_latency_s": _pct(lat, 99),
         "p50_ttft_s": _pct(ttft, 50),
         "p99_ttft_s": _pct(ttft, 99),
+        "p50_queue_wait_s": _pct(wait, 50),
+        "p99_queue_wait_s": _pct(wait, 99),
         "p50_itl_s": _pct(itl, 50),
         "p95_itl_s": _pct(itl, 95),
         "prefill_chunks": float(sum(r.n_chunks for r in requests)),

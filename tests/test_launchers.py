@@ -51,6 +51,17 @@ def test_serve_ssm(tmp_path):
     assert "backend=xla" in out.splitlines()[0]
 
 
+def test_serve_profile_writes_xplane(tmp_path):
+    """``serve --profile DIR``: one profiler session over the serving run,
+    with the engine's phase spans in it."""
+    out = _run(["repro.launch.serve", "--arch", "gemma3-1b", "--smoke",
+                "--batch", "2", "--prompt-len", "8", "--gen", "3",
+                "--profile", str(tmp_path)])
+    assert "out shape (2, 3)" in out
+    path, = tmp_path.rglob("*.xplane.pb")
+    assert b"engine.step" in path.read_bytes()
+
+
 def test_serve_multicodebook(tmp_path):
     out = _run(["repro.launch.serve", "--arch", "musicgen-medium",
                 "--smoke", "--batch", "2", "--prompt-len", "8",

@@ -1,4 +1,4 @@
-"""Observability stack: span tracer, metrics registry, kernel profiler.
+"""Observability stack: span tracer, metrics registry, phase spans.
 
 The invariants under test (ISSUE 8: full-stack observability):
 
@@ -12,26 +12,28 @@ The invariants under test (ISSUE 8: full-stack observability):
   including forced preemption and forced fault fallback — lands as an
   event, and the allocator/engine/fault tracks populate.
 * **Honest math.** Percentiles over empty populations are None (never a
-  fabricated 0.0), and the profiler's contract-derived FLOPs are exact
-  for known shapes across all kernel families.
+  fabricated 0.0).
+* **Phase spans always on.** Every step records its host-loop phases in
+  the registry, on the engine clock, whether or not the ring is on; with
+  the ring on the same spans land there with their parents, and under a
+  profiler session on its host plane.
 """
 
 import json
+import os
+import subprocess
+import sys
+import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.analysis import roofline
-from repro.core.config import GemminiConfig
-from repro.core.context import ExecutionContext
 from repro.models import transformer as tf
-from repro.obs import profile as oprofile
 from repro.obs import trace as otrace
-from repro.obs.metrics import MetricsRegistry
-from repro.obs.profile import Profiler
-from repro.obs.trace import Tracer, req_tid, validate_chrome
+from repro.obs.metrics import Histogram, MetricsRegistry
+from repro.obs.trace import SPANS, Tracer, req_tid, validate_chrome
 from repro.serving.engine import ServingEngine
 from repro.serving.scheduler import _pct
 
@@ -42,11 +44,10 @@ _TINY = tf.ModelConfig(name="tiny-serve", family="dense", n_layers=2,
 
 @pytest.fixture(autouse=True)
 def _no_global_sinks():
-    """Tests must not leak a process-global tracer/profiler into each
-    other (or into the rest of the suite)."""
+    """Tests must not leak a process-global tracer into each other (or
+    into the rest of the suite)."""
     yield
     otrace.deactivate()
-    oprofile.deactivate()
 
 
 def _names(events, cat=None):
@@ -151,7 +152,8 @@ def test_summarize_percentiles_none_for_empty_population():
     s = eng.run()["summary"]
     assert s["requests"] == 0
     for k in ("p50_latency_s", "p99_latency_s", "p50_ttft_s",
-              "p99_ttft_s", "p50_itl_s", "p95_itl_s"):
+              "p99_ttft_s", "p50_itl_s", "p95_itl_s", "p50_queue_wait_s",
+              "p99_queue_wait_s"):
         assert s[k] is None, k
 
 
@@ -199,7 +201,7 @@ def test_lifecycle_events_under_forced_preemption():
             "finished"} <= req_names
     assert any(n.startswith("prefill") for n in req_names)
     assert {"alloc", "evict"} <= set(_names(evs, cat="alloc"))
-    assert "step" in _names(evs, cat="engine")
+    assert "engine.step" in _names(evs, cat="engine")
     assert "arena_pages" in _names(evs, cat="metrics")
     # one lane per request, and every request's lane has a terminal event
     for rid in range(3):
@@ -240,97 +242,229 @@ def test_hang_report_dumps_diagnostics():
 
 
 # ---------------------------------------------------------------------------
-# kernel profiler
+# phase spans: registry always, ring when on, profiler annotations
 # ---------------------------------------------------------------------------
-_CFG = GemminiConfig(input_dtype="bf16", acc_dtype="fp32",
-                     output_dtype="bf16")
+class _TickClock:
+    """A clock that advances 1 ms per reading."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        self.t += 1e-3
+        return self.t
 
 
-def _profiled_ctx():
-    prof = Profiler()
-    oprofile.install(prof)
-    ctx = ExecutionContext(cfg=_CFG, backend="interpret", tune_mode="off")
-    return prof, ctx
+def _chunk_and_decode(eng):
+    """One step that prefills a chunk and decodes: request 0 is prefilled
+    and decoding after the first step; request 1's 11-token prompt takes
+    two 8-position chunks."""
+    rng = np.random.default_rng(0)
+    eng.submit(rng.integers(0, 64, (5,), dtype=np.int32), 4)
+    eng.step()
+    eng.submit(rng.integers(0, 64, (11,), dtype=np.int32), 4)
+    t0 = eng.now()
+    eng.step()
+    return t0, eng.now()
 
 
-def test_profiler_covers_all_kernel_families():
-    """One eager dispatch per kernel family on the interpret backend:
-    every bucket must carry a contract join and a utilization verdict."""
-    prof, ctx = _profiled_ctx()
-    f32, i32 = jnp.float32, jnp.int32
-    # gemm + matmul (gemm engine)
-    ctx.gemm(jnp.ones((16, 32), jnp.bfloat16), jnp.ones((32, 8), jnp.bfloat16))
-    ctx.matmul(jnp.ones((2, 8, 32), jnp.bfloat16),
-               jnp.ones((32, 8), jnp.bfloat16))
-    # conv2d
-    ctx.conv2d(jnp.ones((1, 8, 8, 8), jnp.bfloat16),
-               jnp.ones((3, 3, 8, 8), jnp.bfloat16))
-    # flash attention
-    q = jnp.ones((1, 16, 2, 16), f32)
-    k = jnp.ones((1, 16, 1, 16), f32)
-    ctx.flash_attention(q, k, k)
-    # paged decode + paged prefill
-    pool = jnp.zeros((1, 5, 8, 16), f32)
-    ctx.paged_attention(jnp.ones((2, 1, 2, 16), f32), pool, pool,
-                        jnp.zeros((2, 2), i32), jnp.ones((2,), i32))
-    ctx.paged_prefill_attention(jnp.ones((1, 8, 2, 16), f32), pool, pool,
-                                jnp.zeros((4,), i32), 0)
-    # ssd (mamba-2 mixer)
-    x = jnp.ones((1, 32, 2, 16), f32)
-    ctx.ssd(x, jnp.ones((1, 32, 2), f32), -jnp.ones((2,), f32),
-            jnp.ones((1, 32, 1, 8), f32), jnp.ones((1, 32, 1, 8), f32),
-            chunk=16)
-
-    rows = {r["op"]: r for r in prof.snapshot()}
-    want = {"gemm", "matmul", "conv2d", "flash_attention",
-            "paged_attention", "paged_prefill_attention", "ssd"}
-    assert want <= set(rows)
-    # The CPU has no published peaks: its timings get no utilization.
-    assert prof.peaks is None
-    for op in want:
-        r = rows[op]
-        assert r["contract"], op
-        assert r["flops"] > 0 and r["bytes"] > 0, op
-        assert r["calls"] == 1 and r["min_s"] is not None, op
-        assert r["compute_util"] is None and r["bound"] is None, op
-    # Against a chip's peaks every bucket gets a utilization verdict.
-    prof.peaks = roofline.peaks("TPU v5 lite")
-    rows = {r["op"]: r for r in prof.snapshot()}
-    for op in want:
-        r = rows[op]
-        assert r["compute_util"] is not None and r["compute_util"] >= 0, op
-        assert r["bound"] in ("compute", "memory"), op
-    # contract-derived FLOPs are exact for known shapes
-    assert rows["gemm"]["flops"] == 2.0 * 16 * 8 * 32
-    assert rows["matmul"]["flops"] == 2.0 * 16 * 8 * 32
-    assert rows["flash_attention"]["flops"] == 4.0 * 1 * 2 * 16 * 16 * 16
-    assert "gemm" in prof.report()
+def test_step_phases_in_registry_with_ring_off():
+    eng = _engine(prefill_chunk=8, clock=_TickClock())
+    assert eng.tracer is None
+    t0, t1 = _chunk_and_decode(eng)
+    obs = {n: eng.metrics.observations(n, t0, t1) for n in SPANS}
+    (ts, step), = obs["engine.step"]
+    assert ts >= t0 and ts + step <= t1
+    for name in SPANS:
+        assert obs[name], name
+        for t, d in obs[name]:
+            assert d > 0 and ts <= t and t + d <= ts + step, name
+    # the children are disjoint and lie inside the step
+    kids = sum(d for n in SPANS[1:] for _, d in obs[n])
+    assert kids <= step
+    whiches = {h.labels["which"] for h in eng.metrics._histograms.values()
+               if h.name == "engine.dispatch" and h.count}
+    assert {"prefill_nl", "decode"} <= whiches
 
 
-def test_profiled_dispatch_values_unchanged():
-    a = jnp.asarray(np.random.default_rng(0).standard_normal((16, 32)),
-                    jnp.bfloat16)
-    b = jnp.asarray(np.random.default_rng(1).standard_normal((32, 8)),
-                    jnp.bfloat16)
-    plain_ctx = ExecutionContext(cfg=_CFG, backend="xla", tune_mode="off")
-    want = np.asarray(plain_ctx.gemm(a, b))
-    prof = Profiler()
-    oprofile.install(prof)
-    got = np.asarray(
-        ExecutionContext(cfg=_CFG, backend="xla", tune_mode="off").gemm(a, b))
-    np.testing.assert_array_equal(want, got)
-    assert next(iter(prof.buckets.values())).calls == 1
+def test_step_counters():
+    """What a step counts lives where it was already kept: the
+    scheduler's prefill tokens, the chunks in ``summarize()``, the compile
+    buckets in ``observed_buckets``, whose growth the dispatch span
+    marks."""
+    eng = _engine(prefill_chunk=8)
+    _chunk_and_decode(eng)
+    s = eng.run()["summary"]
+    # true cache positions 5 + 11, in three chunks
+    assert eng.metrics.value("prefill_tokens") == 16
+    assert s["prefill_chunks"] == 3
+    new = {h.labels["which"]: 0 for h in eng.metrics._histograms.values()
+           if h.name == "engine.dispatch"}
+    for h in eng.metrics._histograms.values():
+        if h.name == "engine.dispatch" and h.labels["new_bucket"]:
+            new[h.labels["which"]] += h.count
+    assert new == {w: len(b) for w, b in eng.observed_buckets.items()}
+    assert new["decode"] == 1
 
 
-def test_profiler_emits_kernel_spans_to_tracer():
-    tr = Tracer(capacity=32)
-    prof = Profiler(tracer=tr)
-    oprofile.install(prof)
-    ctx = ExecutionContext(cfg=_CFG, backend="xla", tune_mode="off")
-    ctx.gemm(jnp.ones((8, 8), jnp.bfloat16), jnp.ones((8, 8), jnp.bfloat16))
-    spans = [e for e in tr.events if e.get("cat") == "kernel"]
-    assert len(spans) == 1 and spans[0]["name"] == "gemm"
-    assert spans[0]["args"]["flops"] == 2.0 * 8 * 8 * 8
+def test_sampled_rows_slice_is_prep_not_wait():
+    """The eager slice of the sampled rows is host work queued behind the
+    step, so it lies in ``engine.prep``; ``engine.wait`` holds the sampler
+    alone."""
+    eng = _engine(prefill_chunk=8)
+    seen = {"slice": [], "sample": []}
+
+    class Rows:
+        def __init__(self, logits):
+            self.logits = logits
+
+        def __getitem__(self, idx):
+            seen["slice"].append(eng.spans._open[-1])
+            return self.logits[idx]
+
+    run_guarded, sample = eng._run_guarded, eng._sample
+
+    def guarded(*a):
+        logits, state = run_guarded(*a)
+        return Rows(logits), state
+
+    def sampled(rows):
+        seen["sample"].append(eng.spans._open[-1])
+        return sample(rows)
+
+    eng._run_guarded, eng._sample = guarded, sampled
+    _chunk_and_decode(eng)
+    assert seen["slice"] and set(seen["slice"]) == {"engine.prep"}
+    assert len(seen["sample"]) == len(seen["slice"])
+    assert set(seen["sample"]) == {"engine.wait"}
+
+
+def test_window_picks_exactly_the_steps_inside():
+    eng = _engine(clock=_TickClock())
+    rng = np.random.default_rng(0)
+    eng.submit(rng.integers(0, 64, (5,), dtype=np.int32), 6)
+    bounds = []
+    for _ in range(4):
+        a = eng.now()
+        eng.step()
+        bounds.append((a, eng.now()))
+    lo, hi = bounds[1][0], bounds[2][1]
+    got = [t for t, d in eng.metrics.observations("engine.step", lo, hi)
+           if t + d <= hi]
+    assert len(got) == 2
+    assert bounds[1][0] < got[0] < got[1] < bounds[2][1]
+    assert got[0] < bounds[2][0]
+    after = eng.now()
+    assert eng.metrics.observations("engine.step", after, after + 1.0) == []
+    assert len(eng.metrics.observations("engine.step")) == 4
+
+
+def test_window_none_once_its_observations_are_dropped():
+    """A bounded reservoir that has dropped observations of a window says
+    so, rather than handing a reader part of the window for the whole."""
+    h = Histogram("engine.plan", {}, capacity=4)
+    for i in range(10):
+        h.observe(1.0, float(i))
+    # the last four kept: 6..9; the newest dropped at 5
+    assert h.count == 10 and h.dropped_t == 5.0
+    assert h.window(5.0, 9.0) is None
+    assert [t for t, _ in h.window(5.5, 8.0)] == [6.0, 7.0, 8.0]
+    # untimed observations are dropped without a time
+    u = Histogram("ttft", {}, capacity=2)
+    for v in range(5):
+        u.observe(float(v))
+    assert u.dropped_t is None and u.window(0.0, 1.0) == []
+    # the registry pools label sets: one incomplete set spoils the window
+    m = MetricsRegistry()
+    full = m.histogram("engine.plan", which="decode")
+    for i in range(full.capacity + 1):
+        full.observe(1.0, float(i))
+    m.histogram("engine.plan", which="chunk").observe(1.0, 0.5)
+    assert m.observations("engine.plan") is None
+    assert m.observations("engine.plan", 0.0, 2.0) is None
+    assert m.observations("engine.plan", 0.5, 2.0) == [
+        (0.5, 1.0), (1.0, 1.0), (2.0, 1.0)]
+
+
+def test_spans_annotate_only_the_device_engine():
+    """The tensor-free control plane and the ring tracer's module need no
+    jax; the device-backed engine's spans are profiler annotations."""
+    from repro.analysis.mc.harness import MCConfig, NullEngine
+    assert NullEngine(MCConfig("spans")).spans.annotation is None
+    assert _engine().spans.annotation is jax.profiler.TraceAnnotation
+    code = ("import sys, repro.obs, repro.obs.trace, repro.obs.__main__; "
+            "assert 'jax' not in sys.modules, 'jax imported'")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env)
+
+
+def test_ring_gets_the_same_spans_with_parents():
+    params = tf.init_params(jax.random.PRNGKey(3), _TINY)
+    plain = _engine(params=params, prefill_chunk=8)
+    ringed = _engine(params=params, prefill_chunk=8, trace=True)
+    toks = [_run_tokens(e, np.random.default_rng(1), lens=(5, 11, 20))[0]
+            for e in (plain, ringed)]
+    for a, b in zip(*toks):
+        np.testing.assert_array_equal(a, b)
+    evs = [e for e in ringed.tracer.events
+           if e.get("ph") == "X" and e["name"] in SPANS]
+    assert {e["name"] for e in evs} == set(SPANS)
+    for name in SPANS:
+        n_ring = sum(1 for e in evs if e["name"] == name)
+        assert n_ring == len(ringed.metrics.observations(name)), name
+        assert n_ring == len(plain.metrics.observations(name)), name
+    parents = {e["name"]: set() for e in evs}
+    for e in evs:
+        parents[e["name"]].add(e["args"].get("parent"))
+    assert parents.pop("engine.step") == {None}
+    assert all(p == {"engine.step"} for p in parents.values()), parents
+    disp = [e for e in evs if e["name"] == "engine.dispatch"]
+    assert {e["args"]["which"] for e in disp} >= {"prefill_nl", "decode"}
+    assert sum(e["args"]["new_bucket"] for e in disp) == sum(
+        len(b) for b in ringed.observed_buckets.values())
+    payload = ringed.tracer.chrome()
+    assert validate_chrome(payload) == []
+
+
+def test_spans_nest_on_the_profilers_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+    eng = _engine(prefill_chunk=8)
+    rng = np.random.default_rng(0)
+    eng.submit(rng.integers(0, 64, (5,), dtype=np.int32), 3)
+    eng.step()                                   # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        eng.step()
+    path, = tmp_path.rglob("*.xplane.pb")
+    host = [e for p in ProfileData.from_file(str(path)).planes
+            if p.name.startswith("/host:") for ln in p.lines
+            for e in ln.events if e.name.startswith("engine.")]
+    step, = [e for e in host if e.name == "engine.step"]
+    end = step.start_ns + step.duration_ns
+    inside = {e.name for e in host if e is not step
+              and step.start_ns <= e.start_ns
+              and e.start_ns + e.duration_ns <= end}
+    assert {"engine.plan", "engine.dispatch", "engine.wait"} <= inside
+    disp = next(e for e in host if e.name == "engine.dispatch")
+    with warnings.catch_warnings():
+        # the profiler's stats type lacks __module__ (a jaxlib warning)
+        warnings.simplefilter("ignore", DeprecationWarning)
+        stats = dict(disp.stats)
+    assert stats["which"] == "decode"
+
+
+def test_queue_wait_from_admission_time():
+    clock = _TickClock()
+    eng = _engine(max_slots=1, clock=clock)
+    rng = np.random.default_rng(0)
+    reqs = [eng.submit(rng.integers(0, 64, (5,), dtype=np.int32), 2)
+            for _ in range(2)]
+    s = eng.run()["summary"]
+    assert all(r.t_admitted is not None for r in reqs)
+    # one slot: the second request waits for the first to finish
+    w0, w1 = (r.t_admitted - r.submitted_at for r in reqs)
+    assert 0 < w0 < w1
+    assert s["p50_queue_wait_s"] == pytest.approx((w0 + w1) / 2)
+    assert s["p99_queue_wait_s"] <= w1
 
 
 # ---------------------------------------------------------------------------

@@ -65,7 +65,7 @@ def _attn_contracts(cfg: GemminiConfig):
 
 
 def _paged_contracts(cfg: GemminiConfig):
-    b, h, kvh, d, max_context = 4, 8, 2, 128, 2048
+    b, h, kvh, d, max_context, n_layers = 4, 8, 2, 128, 2048, 2
     for s in schedules.enumerate_paged_schedules(cfg, b, h, kvh, d,
                                                  max_context):
         page = s.effective(max_context).page_size
@@ -73,10 +73,10 @@ def _paged_contracts(cfg: GemminiConfig):
         inst = f"page{page}"
         yield (kc.paged_decode_attention_contract(
             cfg, b=b, h=h, kvh=kvh, d=d, page=page, mp=mp,
-            n_pages=b * mp), cfg, inst)
+            n_pages=b * mp, n_layers=n_layers), cfg, inst)
         yield (kc.paged_prefill_attention_contract(
             cfg, h=h, kvh=kvh, tq=512, d=d, page=page, mp=mp,
-            n_pages=b * mp, block_q=512), cfg, inst)
+            n_pages=b * mp, block_q=512, n_layers=n_layers), cfg, inst)
 
 
 def _conv_contracts(cfg: GemminiConfig):

@@ -398,22 +398,24 @@ def _flash_attention(ctx: ExecutionContext, q, k, v, **kw):
 
 @register_op("paged_attention")
 def _paged_attention(ctx: ExecutionContext, q, k_pool, v_pool, block_tables,
-                     lengths, **kw):
+                     lengths, layer, **kw):
     """Paged-KV single-token decode; under a mesh the decode *slots* are
     partitioned (each device attends its slots against the replicated
-    page pools -- the sequence-sharded arena is the ROADMAP follow-on).
+    stacked page pools -- the sequence-sharded arena is the ROADMAP
+    follow-on).
     See :func:`repro.kernels.ops.paged_attention_impl`."""
     from repro.kernels import ops
     with ctx._tune_scope():
         return ctx._shard_call(
             lambda qq, bt, ln: ops.paged_attention_impl(
-                qq, k_pool, v_pool, bt, ln, backend=ctx.impl_backend, **kw),
+                qq, k_pool, v_pool, bt, ln, layer, backend=ctx.impl_backend,
+                **kw),
             (q, block_tables, lengths), (True, True, True))
 
 
 @register_op("paged_prefill_attention")
 def _paged_prefill_attention(ctx: ExecutionContext, q, k_pool, v_pool,
-                             block_table, start, **kw):
+                             block_table, start, layer, **kw):
     """Chunked-prefill attention over a paged cache. Per-request by
     construction (B == 1), so there is no batch axis to partition and the
     mesh never wraps it; on a sharded engine it runs replicated inside
@@ -422,7 +424,8 @@ def _paged_prefill_attention(ctx: ExecutionContext, q, k_pool, v_pool,
     from repro.kernels import ops
     with ctx._tune_scope():
         return ops.paged_prefill_attention_impl(
-            q, k_pool, v_pool, block_table, start, backend=ctx.impl_backend, **kw)
+            q, k_pool, v_pool, block_table, start, layer,
+            backend=ctx.impl_backend, **kw)
 
 
 @register_op("ssd")

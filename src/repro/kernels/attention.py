@@ -355,43 +355,53 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 @kernel_contract("paged_decode_attention")
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_tables: jnp.ndarray,
-                           lengths: jnp.ndarray, *,
+                           lengths: jnp.ndarray, layer, *,
                            window: Optional[int] = None,
                            softcap: Optional[float] = None,
                            scale: Optional[float] = None,
                            interpret: bool = False) -> jnp.ndarray:
     """Single-token decode against a *paged* KV cache.
 
-    q: (B, 1, H, D); k_pool/v_pool: (KVH, NP, page, D) shared page pools;
-    block_tables: (B, MP) int32 page ids mapping request positions
-    [j*page, (j+1)*page) to pool page ``block_tables[b, j]``; lengths: (B,)
-    int32 live tokens per request (the current token included -- write the
-    KV of the new token first, then attend).
+    q: (B, 1, H, D); k_pool/v_pool: (L, KVH, NP, page, D), every layer's
+    shared page pools stacked; layer: scalar int32 (may be traced), the
+    layer whose pages this call reads; block_tables: (B, MP) int32 page ids
+    mapping request positions [j*page, (j+1)*page) to pool page
+    ``block_tables[b, j]``; lengths: (B,) int32 live tokens per request
+    (the current token included -- write the KV of the new token first,
+    then attend).
 
     The gather happens *inside* the kernel: each (b, kvh, j) grid step's
     K/V BlockSpec index map reads the block table (scalar-prefetched into
-    SMEM) and DMAs exactly one pool page into VMEM -- the pool is never
-    materialized per-request in HBM, which is the whole point of paging.
+    SMEM) and DMAs exactly one page of layer ``layer`` into VMEM -- neither
+    a request's pages nor a layer's pool is ever materialized in HBM, which
+    is the whole point of paging. The stacks enter as their free
+    ``(L*KVH, NP, page, D)`` view and ``layer`` rides at the end of the
+    prefetched lengths, so the row a step reads is ``layer*KVH + kvh``.
     Dead logical pages (j past the request frontier) clamp their index map
     to the last live page, so Mosaic's block-revisiting elides the re-copy,
     and the ``block_live`` predicate skips their compute.
     """
     b, tq, h, d = q.shape
     assert tq == 1
-    kvh, npool, page, _ = k_pool.shape
+    n_layers, kvh, npool, page, _ = k_pool.shape
     mp = block_tables.shape[1]
     rep = h // kvh
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
 
     qg = q[:, 0].reshape(b, kvh, rep, d)
     bt = block_tables.reshape(-1).astype(jnp.int32)          # (B*MP,)
-    lens = lengths.astype(jnp.int32)
+    # (B + 1,): the slots' lengths, then the layer index (at position b)
+    lens = jnp.concatenate([lengths.astype(jnp.int32),
+                            jnp.asarray(layer, jnp.int32).reshape((1,))])
+    kf = k_pool.reshape(n_layers * kvh, npool, page, d)
+    vf = v_pool.reshape(n_layers * kvh, npool, page, d)
 
     def _page_index(bb, hh, j, bt_ref, len_ref):
         # Clamp dead j to the request's last live page: same block index ->
         # Mosaic elides the DMA; an empty request (len 0) pins page bt[b,0].
         jmax = jnp.maximum(len_ref[bb] - 1, 0) // page
-        return (hh, bt_ref[bb * mp + jnp.minimum(j, jmax)], 0, 0)
+        return (len_ref[b] * kvh + hh, bt_ref[bb * mp + jnp.minimum(j, jmax)],
+                0, 0)
 
     kernel = functools.partial(_paged_decode_kernel, npages=mp, page=page,
                                window=window, softcap=softcap, scale=sc)
@@ -420,7 +430,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(bt, lens, qg, k_pool, v_pool)
+    )(bt, lens, qg, kf, vf)
     return out.reshape(b, 1, h, d)
 
 
@@ -485,7 +495,7 @@ def _paged_prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
 @kernel_contract("paged_prefill_attention")
 def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                             v_pool: jnp.ndarray, block_table: jnp.ndarray,
-                            start: jnp.ndarray, *,
+                            start: jnp.ndarray, layer, *,
                             window: Optional[int] = None,
                             softcap: Optional[float] = None,
                             scale: Optional[float] = None,
@@ -494,23 +504,28 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     """Chunked-prefill attention against a *paged* KV cache.
 
     q: (1, T, H, D), one request's fresh chunk of queries at logical
-    positions [start, start + T); k_pool/v_pool: (KVH, NP, page, D) shared
-    page pools, with the chunk's own KV already scattered in (write first,
-    then attend); block_table: (MP,) int32 page ids for THIS request;
-    start: scalar int32 (traced -- one compile serves every chunk offset).
+    positions [start, start + T); k_pool/v_pool: (L, KVH, NP, page, D),
+    every layer's shared page pools stacked, with the chunk's own KV
+    already scattered in (write first, then attend); block_table: (MP,)
+    int32 page ids for THIS request; start: scalar int32 (traced -- one
+    compile serves every chunk offset); layer: scalar int32 (may be
+    traced), the layer whose pages this call reads.
 
     The block-table gather of ``paged_decode_attention`` extended to a
     whole query tile: grid (H, nq, MP) with the page axis innermost, each
     step's K/V BlockSpec index map reading the scalar-prefetched table to
-    DMA one pool page into VMEM. Dead logical pages (beyond what q block i
-    can see under the causal frontier) clamp their index map to the last
-    visible page so Mosaic's block-revisiting elides the copy, and the
-    shared ``block_live`` predicate skips their compute -- a chunk at
-    position s does O(s + T) page work, not O(MP).
+    DMA one page of layer ``layer`` into VMEM (the stacks enter as their
+    free ``(L*KVH, NP, page, D)`` view; ``layer`` rides after ``start`` in
+    the second prefetched array, so a step reads row ``layer*KVH + kvh``).
+    Dead logical pages (beyond what q block i can see under the causal
+    frontier) clamp their index map to the last visible page so Mosaic's
+    block-revisiting elides the copy, and the shared ``block_live``
+    predicate skips their compute -- a chunk at position s does O(s + T)
+    page work, not O(MP).
     """
     b, tq, h, d = q.shape
     assert b == 1, "chunked prefill is per-request (one slot per call)"
-    kvh, npool, page, _ = k_pool.shape
+    n_layers, kvh, npool, page, _ = k_pool.shape
     mp = block_table.shape[0]
     rep = h // kvh
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -522,7 +537,10 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     if pad_q:
         qt = jnp.pad(qt, ((0, 0), (0, pad_q), (0, 0)))
     bt = block_table.reshape(-1).astype(jnp.int32)
-    start_arr = jnp.asarray(start, jnp.int32).reshape((1,))
+    start_arr = jnp.stack([jnp.asarray(start, jnp.int32),
+                           jnp.asarray(layer, jnp.int32)])   # (start, layer)
+    kf = k_pool.reshape(n_layers * kvh, npool, page, d)
+    vf = v_pool.reshape(n_layers * kvh, npool, page, d)
 
     def _page_index(hh, i, j, bt_ref, start_ref):
         # Clamp dead j to the last page visible from q block i (or the
@@ -530,7 +548,8 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         # elides the DMA, and the table is never read out of range.
         qmax = start_ref[0] + (i + 1) * block_q - 1
         jmax = jnp.minimum(qmax, start_ref[0] + tq - 1) // page
-        return (hh // rep, bt_ref[jnp.minimum(j, jmax)], 0, 0)
+        return (start_ref[1] * kvh + hh // rep,
+                bt_ref[jnp.minimum(j, jmax)], 0, 0)
 
     kernel = functools.partial(
         _paged_prefill_kernel, mp=mp, page=page, block_q=block_q, tq=tq,
@@ -560,5 +579,5 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(bt, start_arr, qt, k_pool, v_pool)
+    )(bt, start_arr, qt, kf, vf)
     return jnp.moveaxis(out[:, :tq], 0, 1)[None]           # (1, T, H, D)

@@ -333,19 +333,20 @@ def decode_attention_contract(cfg: GemminiConfig, *, b: int, h: int,
 
 
 _PAGED_GATHER = ("K/V page index gathers through the scalar-prefetched "
-                 "block table; dead steps clamp to the last live page so "
-                 "the read never leaves [0, n_pages)")
+                 "block table, in the rows of the prefetched layer; dead "
+                 "steps clamp to the last live page so the read never "
+                 "leaves [0, n_pages)")
 
 
 @contract_builder("paged_decode_attention")
 def paged_decode_attention_contract(cfg: GemminiConfig, *, b: int, h: int,
                                     kvh: int, d: int, page: int, mp: int,
-                                    n_pages: int, dtype="bf16"
-                                    ) -> KernelContract:
+                                    n_pages: int, n_layers: int = 1,
+                                    dtype="bf16") -> KernelContract:
     io = _attn_dt(dtype)
     f32 = ("float", 4)
     rep = h // kvh
-    pool = (kvh, n_pages, page, d)
+    pool = (n_layers * kvh, n_pages, page, d)      # the stack's free view
     return KernelContract(
         name="paged_decode_attention",
         grid=(("bb", b), ("hh", kvh), ("j", mp)),
@@ -373,12 +374,13 @@ def paged_decode_attention_contract(cfg: GemminiConfig, *, b: int, h: int,
 def paged_prefill_attention_contract(cfg: GemminiConfig, *, h: int, kvh: int,
                                      tq: int, d: int, page: int, mp: int,
                                      n_pages: int, block_q: int,
+                                     n_layers: int = 1,
                                      dtype="bf16") -> KernelContract:
     io = _attn_dt(dtype)
     f32 = ("float", 4)
     block_q = min(block_q, max(tq, 8))
     nq = _cdiv(tq, block_q)
-    pool = (kvh, n_pages, page, d)
+    pool = (n_layers * kvh, n_pages, page, d)      # the stack's free view
     return KernelContract(
         name="paged_prefill_attention",
         grid=(("hh", h), ("i", nq), ("j", mp)),

@@ -340,16 +340,18 @@ def flash_attention_impl(q, k, v, *, causal: bool = True,
 
 
 # -- paged attention ---------------------------------------------------------
-def paged_attention_impl(q, k_pool, v_pool, block_tables, lengths, *,
+def paged_attention_impl(q, k_pool, v_pool, block_tables, lengths, layer, *,
                          window: Optional[int] = None,
                          softcap: Optional[float] = None,
                          scale: Optional[float] = None,
                          backend: Backend = "xla"):
     """Single-token decode over a paged KV cache (the serving engine's hot
-    loop). q: (B, 1, H, D); k_pool/v_pool: (KVH, NP, page, D); block_tables:
-    (B, MP) int32; lengths: (B,) int32 live tokens incl. the current one.
-    Reached as ``ctx.paged_attention(...)``; under a mesh the decode slots
-    (B) are sharded against replicated pools.
+    loop). q: (B, 1, H, D); k_pool/v_pool: (L, KVH, NP, page, D), every
+    layer's pools stacked, read in place; layer: scalar int32 (may be
+    traced), the layer attended; block_tables: (B, MP) int32; lengths: (B,)
+    int32 live tokens incl. the current one. Reached as
+    ``ctx.paged_attention(...)``; under a mesh the decode slots (B) are
+    sharded against replicated pools.
 
     The *page size* is the tuned schedule here -- it is baked into the pool
     shape when the serving engine sizes its cache arena through
@@ -374,16 +376,17 @@ def paged_attention_impl(q, k_pool, v_pool, block_tables, lengths, *,
         from repro.models.attention import (PagedKVCache,
                                             paged_decode_attention_xla)
         cache = PagedKVCache(k_pool, v_pool, block_tables, lengths,
-                             k_pool.shape[2])
+                             k_pool.shape[3], layer)
         return paged_decode_attention_xla(q, cache, window=window,
                                           softcap=softcap, scale=scale)
     from repro.kernels import attention as attn_kernel
     return attn_kernel.paged_decode_attention(
-        q, k_pool, v_pool, block_tables, lengths, window=window,
+        q, k_pool, v_pool, block_tables, lengths, layer, window=window,
         softcap=softcap, scale=scale, interpret=(backend == "interpret"))
 
 
-def paged_prefill_attention_impl(q, k_pool, v_pool, block_table, start, *,
+def paged_prefill_attention_impl(q, k_pool, v_pool, block_table, start,
+                                 layer, *,
                                  window: Optional[int] = None,
                                  softcap: Optional[float] = None,
                                  scale: Optional[float] = None,
@@ -392,10 +395,12 @@ def paged_prefill_attention_impl(q, k_pool, v_pool, block_table, start, *,
     """Chunked-prefill attention over a paged KV cache: one request's fresh
     chunk of queries (q: (1, T, H, D), logical positions [start, start+T))
     attends cache pages + the chunk itself, all through the request's block
-    table (``block_table``: (MP,) int32). The chunk's own KV must already
-    be scattered into the pools (write first, then attend -- the decode
-    discipline); ``start`` may be a traced scalar, so one compile bucket
-    serves every chunk offset of a given chunk length. Reached as
+    table (``block_table``: (MP,) int32) into layer ``layer`` of the
+    stacked pools (``k_pool``/``v_pool``: (L, KVH, NP, page, D), read in
+    place). The chunk's own KV must already be scattered into the pools
+    (write first, then attend -- the decode discipline); ``start`` may be
+    a traced scalar, so one compile bucket serves every chunk offset of a
+    given chunk length. Reached as
     ``ctx.paged_prefill_attention(...)``; per-request (B == 1), so a mesh
     never shards it.
 
@@ -432,12 +437,13 @@ def paged_prefill_attention_impl(q, k_pool, v_pool, block_table, start, *,
         from repro.models.attention import (PagedKVCache,
                                             paged_prefill_attention_xla)
         cache = PagedKVCache(k_pool, v_pool, block_table[None],
-                             jnp.zeros((1,), jnp.int32), k_pool.shape[2])
+                             jnp.zeros((1,), jnp.int32), k_pool.shape[3],
+                             layer)
         return paged_prefill_attention_xla(q, cache, start, window=window,
                                            softcap=softcap, scale=scale)
     from repro.kernels import attention as attn_kernel
     return attn_kernel.paged_prefill_attention(
-        q, k_pool, v_pool, block_table, start, window=window,
+        q, k_pool, v_pool, block_table, start, layer, window=window,
         softcap=softcap, scale=scale, interpret=(backend == "interpret"))
 
 

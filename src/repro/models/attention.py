@@ -148,22 +148,27 @@ class KVCache(NamedTuple):
 
 
 class PagedKVCache(NamedTuple):
-    """One layer's paged KV cache: shared page pools + per-slot tables.
+    """One layer's view of the paged KV cache: the stacked page pools, the
+    layer it reads and writes, and the per-slot tables.
 
-    The pools are the serving engine's HBM page arena (one per layer,
-    allocated once against the config's HBM budget); ``tables``/``lengths``
-    describe every decode slot's view into them. ``page`` rides along as a
-    static int so model code never re-derives it from shapes. For decode,
-    ``active`` masks live slots and ``trash`` names the reserved spill page
-    retired slots write to (see ``paged_update_decode``); prefill ignores
-    both.
+    The pools are the serving engine's whole HBM page arena, every layer's
+    pools stacked (allocated once against the config's HBM budget); a
+    layer scan carries the stacks and names its layer by ``layer``, so the
+    writes land in place and attention reads the layer's pages straight
+    from the stack -- no layer's pool is ever sliced out.
+    ``tables``/``lengths`` describe every decode slot's view into them.
+    ``page`` rides along as a static int so model code never re-derives it
+    from shapes. For decode, ``active`` masks live slots and ``trash``
+    names the reserved spill page retired slots write to (see
+    ``paged_update_decode``); prefill ignores both.
     """
 
-    k: jnp.ndarray             # (KVH, NP, page, D) page pool
-    v: jnp.ndarray             # (KVH, NP, page, D)
+    k: jnp.ndarray             # (L, KVH, NP, page, D) stacked page pools
+    v: jnp.ndarray             # (L, KVH, NP, page, D)
     tables: jnp.ndarray        # (B, MP) int32 page ids per slot
     lengths: jnp.ndarray       # (B,) int32 tokens already cached per slot
     page: int                  # static page size (tokens per page)
+    layer: Any                 # scalar int32 (may be traced): the layer
     active: Optional[jnp.ndarray] = None   # (B,) bool decode-slot liveness
     trash: int = 0                         # reserved spill page id
 
@@ -254,16 +259,27 @@ def update_cache(cache: KVCache, k_new, v_new, pos) -> KVCache:
 # the Pallas paged kernel must match; kernels/attention.paged_decode_attention
 # is the in-kernel-gather TPU lowering)
 # ---------------------------------------------------------------------------
+def _row_index(cache: PagedKVCache, pidx, off):
+    """Index every (token, KV head) row of layer ``cache.layer``: ``pidx``
+    and ``off`` are (N, 1) page ids and in-page offsets, the result indexes
+    an (N, KVH) grid of D-wide rows. Only the head dimension is left to the
+    scatter's window, so the stacks keep their layout and the write lands
+    in place."""
+    heads = jnp.arange(cache.k.shape[1], dtype=jnp.int32)[None, :]
+    return cache.layer, heads, pidx, off
+
+
 def paged_update_decode(cache: PagedKVCache, k_new, v_new,
                         active: jnp.ndarray, trash_page: int) -> PagedKVCache:
     """Write one decode token per slot into its paged position.
 
     k_new/v_new: (B, 1, KVH, D); slot b's token lands at logical position
-    ``lengths[b]`` = pool page ``tables[b, lengths[b]//page]``, offset
-    ``lengths[b] % page``. Inactive slots (finished/empty -- ``active``
-    False) are redirected to the reserved ``trash_page`` so a retired slot
-    can never corrupt pages the allocator has handed to another request,
-    and their lengths stay frozen.
+    ``lengths[b]`` = page ``tables[b, lengths[b]//page]`` of layer
+    ``cache.layer``, offset ``lengths[b] % page``, written into the stacks
+    in place (B x KVH x D values move). Inactive slots (finished/empty --
+    ``active`` False) are redirected to the layer's reserved ``trash_page``
+    so a retired slot can never corrupt pages the allocator has handed to
+    another request, and their lengths stay frozen.
     """
     page = cache.page
     mp = cache.tables.shape[1]
@@ -274,10 +290,9 @@ def paged_update_decode(cache: PagedKVCache, k_new, v_new,
     pidx = jnp.take_along_axis(cache.tables, col, axis=1)[:, 0]
     pidx = jnp.where(active, pidx, jnp.int32(trash_page))
     off = cache.lengths % page
-    kt = jnp.moveaxis(k_new[:, 0], 1, 0).astype(cache.k.dtype)   # (KVH, B, D)
-    vt = jnp.moveaxis(v_new[:, 0], 1, 0).astype(cache.v.dtype)
-    k = cache.k.at[:, pidx, off].set(kt)
-    v = cache.v.at[:, pidx, off].set(vt)
+    idx = _row_index(cache, pidx[:, None], off[:, None])
+    k = cache.k.at[idx].set(k_new[:, 0].astype(cache.k.dtype))
+    v = cache.v.at[idx].set(v_new[:, 0].astype(cache.v.dtype))
     lengths = jnp.where(active, cache.lengths + 1, cache.lengths)
     return cache._replace(k=k, v=v, lengths=lengths)
 
@@ -286,8 +301,9 @@ def paged_update_prefill(cache: PagedKVCache, k_new, v_new,
                          pages: jnp.ndarray, start=0) -> PagedKVCache:
     """Scatter a prompt (or prompt chunk) KV into the pages allocated for it.
 
-    k_new/v_new: (1, T, KVH, D); ``pages``: (MP,) page ids covering logical
-    positions [0, start + T) (entries past ceil((start+T)/page) unused);
+    k_new/v_new: (1, T, KVH, D), written in place into layer ``cache.layer``
+    of the stacks; ``pages``: (MP,) page ids covering logical positions
+    [0, start + T) (entries past ceil((start+T)/page) unused);
     ``start``: logical position of the chunk's first token (0 for a fresh
     whole-prompt prefill; a traced scalar for chunked-prefill continuation
     chunks). Positions past the true prompt length are bucket padding --
@@ -299,10 +315,10 @@ def paged_update_prefill(cache: PagedKVCache, k_new, v_new,
     pos = start + jnp.arange(t)
     pidx = pages[pos // page]
     off = pos % page
-    kt = jnp.moveaxis(k_new[0], 1, 0).astype(cache.k.dtype)      # (KVH, T, D)
-    vt = jnp.moveaxis(v_new[0], 1, 0).astype(cache.v.dtype)
-    return cache._replace(k=cache.k.at[:, pidx, off].set(kt),
-                          v=cache.v.at[:, pidx, off].set(vt))
+    idx = _row_index(cache, pidx[:, None], off[:, None])
+    k = cache.k.at[idx].set(k_new[0].astype(cache.k.dtype))
+    v = cache.v.at[idx].set(v_new[0].astype(cache.v.dtype))
+    return cache._replace(k=k, v=v)
 
 
 def paged_decode_attention_xla(q, cache: PagedKVCache, *,
@@ -312,7 +328,9 @@ def paged_decode_attention_xla(q, cache: PagedKVCache, *,
     """One-token attention over a paged cache, by explicit gather.
 
     q: (B, 1, H, D); ``cache.lengths`` counts the live tokens *including*
-    the current one (write first, then attend). Numerics mirror
+    the current one (write first, then attend). The gather indexes layer
+    ``cache.layer`` of the stacked pools inside its one gather, so no
+    layer's pool is sliced out first. Numerics mirror
     ``decode_attention`` exactly -- same einsums, same staging, same
     mask-then-softmax, including the ``gqa_grouped_decode`` flag branch --
     so a request decoded through the paged path is bit-identical to the
@@ -321,16 +339,16 @@ def paged_decode_attention_xla(q, cache: PagedKVCache, *,
     """
     from repro.core import flags
     b, tq, h, d = q.shape
-    kvh, _, page, _ = cache.k.shape
+    _, kvh, _, page, _ = cache.k.shape
     rep = h // kvh
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
     mp = cache.tables.shape[1]
     s_ctx = mp * page
 
-    # (KVH, B, MP, page, D) -> (B, S_ctx, KVH, D) logical-position order
+    # (B, MP, KVH, page, D) -> (B, S_ctx, KVH, D) logical-position order
     def gather(pool):
-        g = pool[:, cache.tables]
-        return jnp.transpose(g, (1, 2, 3, 0, 4)).reshape(b, s_ctx, kvh, d)
+        g = pool[cache.layer, :, cache.tables]
+        return jnp.transpose(g, (0, 1, 3, 2, 4)).reshape(b, s_ctx, kvh, d)
 
     kpos = jnp.arange(s_ctx)
     pos = (cache.lengths - 1)[:, None]                  # (B, 1)
@@ -392,13 +410,13 @@ def paged_prefill_attention_xla(q, cache: PagedKVCache, start, *,
     the causal mask, exactly like the reference's pad_k region.
     """
     b, tq, h, d = q.shape
-    kvh, _, page, _ = cache.k.shape
+    _, kvh, _, page, _ = cache.k.shape
     mp = cache.tables.shape[1]
     s_ctx = mp * page
 
     def gather(pool):
-        g = pool[:, cache.tables]
-        return jnp.transpose(g, (1, 2, 3, 0, 4)).reshape(b, s_ctx, kvh, d)
+        g = pool[cache.layer, :, cache.tables]
+        return jnp.transpose(g, (0, 1, 3, 2, 4)).reshape(b, s_ctx, kvh, d)
 
     return blockwise_attention_xla(
         q, gather(cache.k), gather(cache.v), causal=True, window=window,
@@ -451,8 +469,8 @@ def paged_attn_op(engine, q, cache: PagedKVCache, *, window=None,
     gather path, whose masking handles traced scalars."""
     window, ctx = _route_window(engine, window)
     return ctx.paged_attention(q, cache.k, cache.v, cache.tables,
-                               cache.lengths, window=window, softcap=softcap,
-                               scale=scale)
+                               cache.lengths, cache.layer, window=window,
+                               softcap=softcap, scale=scale)
 
 
 def paged_prefill_attn_op(engine, q, cache: PagedKVCache, start, *,
@@ -469,8 +487,8 @@ def paged_prefill_attn_op(engine, q, cache: PagedKVCache, start, *,
     ``ops.paged_prefill_attention_impl``)."""
     window, ctx = _route_window(engine, window)
     return ctx.paged_prefill_attention(
-        q, cache.k, cache.v, cache.tables[0], start, window=window,
-        softcap=softcap, scale=scale, kv_pages=kv_pages)
+        q, cache.k, cache.v, cache.tables[0], start, cache.layer,
+        window=window, softcap=softcap, scale=scale, kv_pages=kv_pages)
 
 
 # ---------------------------------------------------------------------------

@@ -749,10 +749,13 @@ class PagedDecodeState(NamedTuple):
 
     Unlike :class:`DecodeState` (one contiguous (B, S) cache, one shared
     scalar position), slots here are independent requests at independent
-    positions: per-layer page pools shared by every slot, per-slot block
-    tables mapping logical positions to pool pages, and per-slot lengths.
-    The last pool page (id NP) is the reserved trash page retired slots
-    spill to; the allocator only ever hands out ids [0, NP).
+    positions: page pools shared by every slot, every layer's stacked in
+    one array (the paged steps carry the stacks through the layer scan and
+    index a layer's pages by layer index, see ``_scan_paged``), per-slot
+    block tables mapping logical positions to pool pages, and per-slot
+    lengths. The last pool page (id NP) of every layer is the reserved
+    trash page retired slots spill to; the allocator only ever hands out
+    ids [0, NP).
     """
 
     kv_k: Optional[jnp.ndarray]       # (L, KVH, NP + 1, page, D) or None
@@ -781,6 +784,36 @@ def init_paged_state(cfg: ModelConfig, slots: int, n_pages: int,
     return PagedDecodeState(kv_k, kv_v, conv, st,
                             jnp.zeros((slots, max_pages), jnp.int32),
                             jnp.zeros((slots,), jnp.int32))
+
+
+def _scan_paged(cfg: ModelConfig, params: Params, h: jnp.ndarray,
+                state: PagedDecodeState, windows, bases, cache, block
+                ) -> Tuple[jnp.ndarray, PagedDecodeState]:
+    """Scan the decoder blocks over paged state.
+
+    The stacked KV pools ride the scan's carry whole: each layer writes
+    its tokens into them in place and its attention reads its own pages
+    straight from the stack, by layer index. No layer's pool is sliced
+    out or stacked back, which would copy the arena every layer. The SSM
+    slot state (small, per slot) rides ``xs``/``ys``.
+
+    ``cache(kv_k, kv_v, li)`` builds layer ``li``'s
+    :class:`attn.PagedKVCache`; ``block(h, bp, win, base, kvc, conv, st)``
+    runs one block and returns ``(h, kvc, conv, st)``.
+    """
+    def body(carry, xs):
+        h, kv_k, kv_v = carry
+        bp, win, base, li, conv, st = xs
+        kvc = cache(kv_k, kv_v, li) if kv_k is not None else None
+        h, kvc, conv, st = block(h, bp, win, base, kvc, conv, st)
+        kv_k, kv_v = (kvc.k, kvc.v) if kvc is not None else (None, None)
+        return (h, kv_k, kv_v), (conv, st)
+
+    xs = (params["blocks"], windows, bases,
+          jnp.arange(cfg.n_layers, dtype=jnp.int32), state.conv, state.ssm)
+    (h, kv_k, kv_v), (conv, st) = jax.lax.scan(
+        body, (h, state.kv_k, state.kv_v), xs)
+    return h, state._replace(kv_k=kv_k, kv_v=kv_v, conv=conv, ssm=st)
 
 
 def paged_prefill(engine: GemminiInstance, params: Params, cfg: ModelConfig,
@@ -814,12 +847,7 @@ def paged_prefill(engine: GemminiInstance, params: Params, cfg: ModelConfig,
     bases = jnp.asarray(layer_rope_bases(cfg))
     zero_len = jnp.zeros((1,), jnp.int32)
 
-    def body(h, xs):
-        bp, win, base, kv_k, kv_v, conv, st = xs
-        kvc = None
-        if kv_k is not None:
-            kvc = attn.PagedKVCache(kv_k, kv_v, pages[None], zero_len,
-                                    page_size)
+    def block(h, bp, win, base, kvc, conv, st):
         ssc = None
         if conv is not None:
             # Fresh request: conv state zeroed, recurrent state spelled
@@ -830,21 +858,19 @@ def paged_prefill(engine: GemminiInstance, params: Params, cfg: ModelConfig,
         h, kvc, ssc = _block_apply(engine, cfg, bp, h, positions, win, base,
                                    kv_cache=kvc, ssm_cache=ssc,
                                    window_static=static_win)
-        new = (kvc.k if kvc else None, kvc.v if kvc else None,
-               jax.lax.dynamic_update_slice_in_dim(
-                   conv, ssc.conv.astype(conv.dtype), slot, 0)
-               if ssc else None,
-               jax.lax.dynamic_update_slice_in_dim(
-                   st, ssc.state.astype(st.dtype), slot, 0)
-               if ssc else None)
-        return h, new
+        if ssc is not None:
+            conv = jax.lax.dynamic_update_slice_in_dim(
+                conv, ssc.conv.astype(conv.dtype), slot, 0)
+            st = jax.lax.dynamic_update_slice_in_dim(
+                st, ssc.state.astype(st.dtype), slot, 0)
+        return h, kvc, conv, st
 
-    xs = (params["blocks"], windows, bases, state.kv_k, state.kv_v,
-          state.conv, state.ssm)
-    h, caches = jax.lax.scan(body, h, xs)
-    kv_k, kv_v, conv, st = caches
+    h, state = _scan_paged(
+        cfg, params, h, state, windows, bases,
+        lambda k, v, li: attn.PagedKVCache(k, v, pages[None], zero_len,
+                                           page_size, li), block)
     logits = unembed(engine, cfg, params, h) if with_logits else None
-    return logits, state._replace(kv_k=kv_k, kv_v=kv_v, conv=conv, ssm=st)
+    return logits, state
 
 
 def paged_prefill_chunk(engine: GemminiInstance, params: Params,
@@ -893,36 +919,28 @@ def paged_prefill_chunk(engine: GemminiInstance, params: Params,
     bases = jnp.asarray(layer_rope_bases(cfg))
     zero_len = jnp.zeros((1,), jnp.int32)
 
-    def body(h, xs):
-        bp, win, base, kv_k, kv_v, conv, st = xs
-        kvc = None
-        if kv_k is not None:
-            kvc = attn.PagedKVCache(kv_k, kv_v, pages[None], zero_len,
-                                    page_size)
+    def block(h, bp, win, base, kvc, conv, st):
         ssc = None
         if conv is not None:
-            c1 = jax.lax.dynamic_slice_in_dim(conv, slot, 1, 0)
-            s1 = jax.lax.dynamic_slice_in_dim(st, slot, 1, 0)
-            ssc = ssm.SSMCache(c1, s1)
+            ssc = ssm.SSMCache(jax.lax.dynamic_slice_in_dim(conv, slot, 1, 0),
+                               jax.lax.dynamic_slice_in_dim(st, slot, 1, 0))
         h, kvc, ssc = _block_apply(engine, cfg, bp, h, positions, win, base,
                                    kv_cache=kvc, ssm_cache=ssc,
                                    window_static=static_win,
                                    prefill_start=start, kv_pages=kv_pages)
-        new = (kvc.k if kvc else None, kvc.v if kvc else None,
-               jax.lax.dynamic_update_slice_in_dim(
-                   conv, ssc.conv.astype(conv.dtype), slot, 0)
-               if ssc else None,
-               jax.lax.dynamic_update_slice_in_dim(
-                   st, ssc.state.astype(st.dtype), slot, 0)
-               if ssc else None)
-        return h, new
+        if ssc is not None:
+            conv = jax.lax.dynamic_update_slice_in_dim(
+                conv, ssc.conv.astype(conv.dtype), slot, 0)
+            st = jax.lax.dynamic_update_slice_in_dim(
+                st, ssc.state.astype(st.dtype), slot, 0)
+        return h, kvc, conv, st
 
-    xs = (params["blocks"], windows, bases, state.kv_k, state.kv_v,
-          state.conv, state.ssm)
-    h, caches = jax.lax.scan(body, h, xs)
-    kv_k, kv_v, conv, st = caches
+    h, state = _scan_paged(
+        cfg, params, h, state, windows, bases,
+        lambda k, v, li: attn.PagedKVCache(k, v, pages[None], zero_len,
+                                           page_size, li), block)
     logits = unembed(engine, cfg, params, h) if with_logits else None
-    return logits, state._replace(kv_k=kv_k, kv_v=kv_v, conv=conv, ssm=st)
+    return logits, state
 
 
 def paged_decode_step(engine: GemminiInstance, params: Params,
@@ -958,30 +976,23 @@ def paged_decode_step(engine: GemminiInstance, params: Params,
     bases = jnp.asarray(layer_rope_bases(cfg))
     trash = state.kv_k.shape[2] - 1 if state.kv_k is not None else 0
 
-    def body(h, xs):
-        bp, win, base, kv_k, kv_v, conv, st = xs
-        kvc = None
-        if kv_k is not None:
-            kvc = attn.PagedKVCache(kv_k, kv_v, state.tables, state.lengths,
-                                    page_size, active, trash)
+    def block(h, bp, win, base, kvc, conv, st):
         ssc = ssm.SSMCache(conv, st) if conv is not None else None
         h, kvc, ssc = _block_apply(engine, cfg, bp, h, positions, win, base,
                                    kv_cache=kvc, ssm_cache=ssc,
                                    window_static=static_win)
-        new = (kvc.k if kvc else None, kvc.v if kvc else None,
-               jnp.where(active[:, None, None],
-                         ssc.conv.astype(conv.dtype), conv)
-               if ssc else None,
-               jnp.where(active[:, None, None, None],
-                         ssc.state.astype(st.dtype), st)
-               if ssc else None)
-        return h, new
+        if ssc is not None:
+            conv = jnp.where(active[:, None, None],
+                             ssc.conv.astype(conv.dtype), conv)
+            st = jnp.where(active[:, None, None, None],
+                           ssc.state.astype(st.dtype), st)
+        return h, kvc, conv, st
 
-    xs = (params["blocks"], windows, bases, state.kv_k, state.kv_v,
-          state.conv, state.ssm)
-    h, caches = jax.lax.scan(body, h, xs)
-    kv_k, kv_v, conv, st = caches
+    h, state = _scan_paged(
+        cfg, params, h, state, windows, bases,
+        lambda k, v, li: attn.PagedKVCache(k, v, state.tables, state.lengths,
+                                           page_size, li, active, trash),
+        block)
     logits = unembed(engine, cfg, params, h)
     lengths = jnp.where(active, state.lengths + 1, state.lengths)
-    return logits, state._replace(kv_k=kv_k, kv_v=kv_v, conv=conv, ssm=st,
-                                  lengths=lengths)
+    return logits, state._replace(lengths=lengths)

@@ -159,8 +159,8 @@ def measure_paged_schedule(cfg: GemminiConfig, sched, b: int, h: int,
     mp = -(-max_context // page)
     n_pages = b * mp
     q = jnp.zeros((b, 1, h, d), dt)
-    k_pool = jnp.zeros((kvh, n_pages + 1, page, d), dt)
-    v_pool = jnp.zeros((kvh, n_pages + 1, page, d), dt)
+    k_pool = jnp.zeros((1, kvh, n_pages + 1, page, d), dt)   # one layer
+    v_pool = jnp.zeros((1, kvh, n_pages + 1, page, d), dt)
     tables = jnp.arange(b * mp, dtype=jnp.int32).reshape(b, mp)
     lengths = jnp.full((b,), max_context, jnp.int32)
     ctx = ExecutionContext(
@@ -168,7 +168,7 @@ def measure_paged_schedule(cfg: GemminiConfig, sched, b: int, h: int,
         tune_mode="off")   # measuring: never recurse into the tuner
 
     def run(q, k_pool, v_pool):
-        return ctx.paged_attention(q, k_pool, v_pool, tables, lengths,
+        return ctx.paged_attention(q, k_pool, v_pool, tables, lengths, 0,
                                    window=window)
 
     return time_callable(jax.jit(run), q, k_pool, v_pool, iters=iters,

@@ -220,16 +220,17 @@ def test_paged_prefill_kv_pages_bound_exact(rng, backend):
     h, kvh, d, page, mp = 4, 2, 16, 8, 12
     start, tq = 8, 8                          # chunk 2 of a 16-token prompt
     kv_pages = 2                              # covers start + tq = 16 keys
-    pool_shape = (kvh, mp + 1, page, d)
+    pool_shape = (1, kvh, mp + 1, page, d)        # a one-layer stack
     k_pool = jnp.asarray(rng.standard_normal(pool_shape), jnp.float32)
     v_pool = jnp.asarray(rng.standard_normal(pool_shape), jnp.float32)
     table = jnp.asarray(rng.permutation(mp).astype(np.int32))
     q = jnp.asarray(rng.standard_normal((1, tq, h, d)), jnp.float32)
     ctx = ExecutionContext(backend=backend)
     full = ctx.paged_prefill_attention(q, k_pool, v_pool, table,
-                                       jnp.int32(start))
+                                       jnp.int32(start), 0)
     tight = ctx.paged_prefill_attention(q, k_pool, v_pool, table,
-                                        jnp.int32(start), kv_pages=kv_pages)
+                                        jnp.int32(start), 0,
+                                        kv_pages=kv_pages)
     np.testing.assert_array_equal(np.asarray(tight), np.asarray(full))
 
 
@@ -237,7 +238,7 @@ def test_paged_prefill_kv_pages_cuts_gathered_keys():
     """The xla twin's gather really shrinks: the contracted key axis is
     the 128-clamped kv_pages * page width, not the table capacity."""
     h, kvh, d, page, mp = 2, 1, 8, 8, 32     # capacity 256 keys
-    pool = jnp.zeros((kvh, mp + 1, page, d), jnp.float32)
+    pool = jnp.zeros((1, kvh, mp + 1, page, d), jnp.float32)
     table = jnp.arange(mp, dtype=jnp.int32)
     q = jnp.zeros((1, 8, h, d), jnp.float32)
     ctx = ExecutionContext(backend="xla")
@@ -245,7 +246,7 @@ def test_paged_prefill_kv_pages_cuts_gathered_keys():
     def width(kv_pages):
         jaxpr = jax.make_jaxpr(
             lambda q, k, v: ctx.paged_prefill_attention(
-                q, k, v, table, jnp.int32(0), kv_pages=kv_pages))(
+                q, k, v, table, jnp.int32(0), 0, kv_pages=kv_pages))(
             q, pool, pool)
         # widest KV-shaped intermediate = the gathered/padded key axis
         return max(v.aval.shape[1] for e in jaxpr.jaxpr.eqns

@@ -161,65 +161,85 @@ def test_defrag_with_held_and_refcount_shared_pages():
 # ---------------------------------------------------------------------------
 # paged attention numerics
 # ---------------------------------------------------------------------------
-def _scattered_case(rng, b, h, kvh, d, page, mp, lens, poison=np.nan):
-    """Contiguous per-request K/V plus the equivalent shuffled page pools."""
+def _scattered_case(rng, b, h, kvh, d, page, mp, lens, poison=np.nan,
+                    n_layers=1):
+    """Contiguous per-request K/V of every layer plus the equivalent
+    shuffled page pools, stacked as the paged steps carry them: kc/vc
+    (L, B, MP*page, KVH, D), pools (L, KVH, NP, page, D), one block table
+    for all layers. Each layer holds values of its own."""
     n_pool = b * mp + 2
-    pool_k = np.full((kvh, n_pool, page, d), poison, np.float32)
-    pool_v = np.full((kvh, n_pool, page, d), poison, np.float32)
+    pool_k = np.full((n_layers, kvh, n_pool, page, d), poison, np.float32)
+    pool_v = np.full((n_layers, kvh, n_pool, page, d), poison, np.float32)
     tables = np.zeros((b, mp), np.int32)
     free = list(rng.permutation(n_pool))
-    kc = rng.standard_normal((b, mp * page, kvh, d)).astype(np.float32)
-    vc = rng.standard_normal((b, mp * page, kvh, d)).astype(np.float32)
+    kc = rng.standard_normal((n_layers, b, mp * page, kvh, d)).astype(
+        np.float32)
+    vc = rng.standard_normal((n_layers, b, mp * page, kvh, d)).astype(
+        np.float32)
     for bb in range(b):
         for j in range(pages_for(int(lens[bb]), page)):
             pid = free.pop()
             tables[bb, j] = pid
-            pool_k[:, pid] = kc[bb, j * page:(j + 1) * page].transpose(1, 0, 2)
-            pool_v[:, pid] = vc[bb, j * page:(j + 1) * page].transpose(1, 0, 2)
+            sl = slice(j * page, (j + 1) * page)
+            pool_k[:, :, pid] = kc[:, bb, sl].transpose(0, 2, 1, 3)
+            pool_v[:, :, pid] = vc[:, bb, sl].transpose(0, 2, 1, 3)
     return kc, vc, pool_k, pool_v, tables
 
 
-@pytest.mark.parametrize("h,kvh,win,cap", [(4, 2, None, None), (4, 1, 24, None),
-                                           (8, 8, None, 30.0)])
-def test_paged_kernel_vs_oracle(rng, h, kvh, win, cap):
+@pytest.mark.parametrize("h,kvh,win,cap,n_layers", [
+    pytest.param(4, 2, None, None, 1, id="4-2-None-None"),
+    pytest.param(4, 1, 24, None, 1, id="4-1-24-None"),
+    pytest.param(8, 8, None, 30.0, 1, id="8-8-None-30.0"),
+    pytest.param(4, 2, None, None, 3, id="4-2-None-None-3layers")])
+def test_paged_kernel_vs_oracle(rng, h, kvh, win, cap, n_layers):
     """The Pallas paged-decode kernel (interpret mode) matches the dense
     oracle on scattered, NaN-poisoned pools: dead pages are skipped, the
-    partial tail page is masked."""
+    partial tail page is masked. On a stack of layers, layer li's output
+    is the oracle's on layer li's values alone."""
     b, d, page, mp = 3, 32, 16, 5
     lens = np.array([37, 1, 80], np.int32)       # partial / tiny / full
     kc, vc, pk, pv, tables = _scattered_case(rng, b, h, kvh, d, page, mp,
-                                             lens)
+                                             lens, n_layers=n_layers)
     q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-    y = ak.paged_decode_attention(q, jnp.asarray(pk), jnp.asarray(pv),
-                                  jnp.asarray(tables), jnp.asarray(lens),
-                                  window=win, softcap=cap, interpret=True)
-    for bb in range(b):
-        L = int(lens[bb])
-        yr = ref.mha_ref(q[bb:bb + 1], jnp.asarray(kc[bb:bb + 1, :L]),
-                         jnp.asarray(vc[bb:bb + 1, :L]), causal=True,
-                         window=win, softcap=cap)
-        np.testing.assert_allclose(np.asarray(y[bb]), np.asarray(yr[0]),
-                                   rtol=2e-5, atol=2e-5)
+    for li in range(n_layers):
+        y = ak.paged_decode_attention(q, jnp.asarray(pk), jnp.asarray(pv),
+                                      jnp.asarray(tables), jnp.asarray(lens),
+                                      jnp.int32(li), window=win, softcap=cap,
+                                      interpret=True)
+        for bb in range(b):
+            L = int(lens[bb])
+            yr = ref.mha_ref(q[bb:bb + 1],
+                             jnp.asarray(kc[li, bb:bb + 1, :L]),
+                             jnp.asarray(vc[li, bb:bb + 1, :L]), causal=True,
+                             window=win, softcap=cap)
+            np.testing.assert_allclose(np.asarray(y[bb]), np.asarray(yr[0]),
+                                       rtol=2e-5, atol=2e-5)
 
 
 def test_paged_xla_equals_dense_decode(rng):
     """The explicit-gather XLA path is exactly the dense decode_attention
-    computation (same einsums/mask/softmax), request by request -- zeros
-    in unwritten pool entries, as the engine allocates them."""
-    b, h, kvh, d, page, mp = 2, 4, 2, 16, 8, 4
+    computation (same einsums/mask/softmax), request by request and layer
+    by layer of a stack -- zeros in unwritten pool entries, as the engine
+    allocates them."""
+    b, h, kvh, d, page, mp, n_layers = 2, 4, 2, 16, 8, 4, 3
     lens = np.array([19, 27], np.int32)
     kc, vc, pk, pv, tables = _scattered_case(rng, b, h, kvh, d, page, mp,
-                                             lens, poison=0.0)
+                                             lens, poison=0.0,
+                                             n_layers=n_layers)
     q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-    cache = mattn.PagedKVCache(jnp.asarray(pk), jnp.asarray(pv),
-                               jnp.asarray(tables), jnp.asarray(lens), page)
-    y = mattn.paged_decode_attention_xla(q, cache, window=8)
-    for bb in range(b):
-        dense = mattn.KVCache(jnp.asarray(kc[bb:bb + 1]),
-                              jnp.asarray(vc[bb:bb + 1]))
-        yd = mattn.decode_attention(q[bb:bb + 1], dense,
-                                    jnp.int32(int(lens[bb]) - 1), window=8)
-        np.testing.assert_array_equal(np.asarray(y[bb]), np.asarray(yd[0]))
+    for li in range(n_layers):
+        cache = mattn.PagedKVCache(jnp.asarray(pk), jnp.asarray(pv),
+                                   jnp.asarray(tables), jnp.asarray(lens),
+                                   page, jnp.int32(li))
+        y = mattn.paged_decode_attention_xla(q, cache, window=8)
+        for bb in range(b):
+            dense = mattn.KVCache(jnp.asarray(kc[li, bb:bb + 1]),
+                                  jnp.asarray(vc[li, bb:bb + 1]))
+            yd = mattn.decode_attention(q[bb:bb + 1], dense,
+                                        jnp.int32(int(lens[bb]) - 1),
+                                        window=8)
+            np.testing.assert_array_equal(np.asarray(y[bb]),
+                                          np.asarray(yd[0]))
 
 
 def test_paged_xla_grouped_decode_flag_parity(rng):
@@ -232,14 +252,15 @@ def test_paged_xla_grouped_decode_flag_parity(rng):
                                              lens, poison=0.0)
     q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
     cache = mattn.PagedKVCache(jnp.asarray(pk), jnp.asarray(pv),
-                               jnp.asarray(tables), jnp.asarray(lens), page)
+                               jnp.asarray(tables), jnp.asarray(lens), page,
+                               jnp.int32(0))
     prev = flags.get("gqa_grouped_decode")
     flags.set_flag("gqa_grouped_decode", True)
     try:
         y = mattn.paged_decode_attention_xla(q, cache)
         for bb in range(b):
-            dense = mattn.KVCache(jnp.asarray(kc[bb:bb + 1]),
-                                  jnp.asarray(vc[bb:bb + 1]))
+            dense = mattn.KVCache(jnp.asarray(kc[0, bb:bb + 1]),
+                                  jnp.asarray(vc[0, bb:bb + 1]))
             yd = mattn.decode_attention(q[bb:bb + 1], dense,
                                         jnp.int32(int(lens[bb]) - 1))
             np.testing.assert_array_equal(np.asarray(y[bb]),
@@ -248,29 +269,60 @@ def test_paged_xla_grouped_decode_flag_parity(rng):
         flags.set_flag("gqa_grouped_decode", prev)
 
 
-def test_paged_update_roundtrip(rng):
-    """Prefill scatter + decode scatter land tokens at the right logical
-    positions; inactive slots spill to the trash page only."""
-    kvh, d, page, np_pages, mp, slots = 2, 8, 4, 6, 3, 2
-    pool = jnp.zeros((kvh, np_pages + 1, page, d), jnp.float32)
+def _roundtrip(rng, n_layers, li):
+    """Prefill scatter, then a decode scatter with slot 1 inactive, into
+    layer ``li`` of a stack of ``n_layers`` distinct layers. Returns the
+    stack before, the two results, the written values and the geometry."""
+    kvh, d, page, np_pages = 2, 8, 4, 6
+    pool = jnp.asarray(rng.standard_normal((n_layers, kvh, np_pages + 1,
+                                            page, d)), jnp.float32)
     cache = mattn.PagedKVCache(pool, pool, jnp.asarray([[3, 1, 0], [2, 4, 0]],
                                                        jnp.int32),
-                               jnp.asarray([5, 0], jnp.int32), page)
+                               jnp.asarray([5, 0], jnp.int32), page,
+                               jnp.int32(li), jnp.asarray([True, False]),
+                               np_pages)
     kc = jnp.asarray(rng.standard_normal((1, 6, kvh, d)), jnp.float32)
     up = mattn.paged_update_prefill(cache, kc, kc, cache.tables[0])
-    # position 5 -> page tables[0][1]=1, offset 1
-    np.testing.assert_array_equal(np.asarray(up.k[:, 1, 1]),
-                                  np.asarray(kc[0, 5]))
     # decode write: slot0 at len=5 -> page 1 offset 1; slot1 inactive ->
     # trash page (id np_pages), lengths frozen
-    k1 = jnp.asarray(rng.standard_normal((slots, 1, kvh, d)), jnp.float32)
-    dec = mattn.paged_update_decode(
-        cache._replace(lengths=jnp.asarray([5, 0], jnp.int32)), k1, k1,
-        jnp.asarray([True, False]), np_pages)
-    np.testing.assert_array_equal(np.asarray(dec.k[:, 1, 1]),
+    k1 = jnp.asarray(rng.standard_normal((2, 1, kvh, d)), jnp.float32)
+    dec = mattn.paged_update_decode(cache, k1, k1, cache.active, cache.trash)
+    return pool, up, dec, kc, k1, np_pages
+
+
+def test_paged_update_roundtrip(rng):
+    """Prefill scatter + decode scatter land tokens at the right logical
+    positions of the named layer (layer 1 of three); inactive slots spill
+    to the trash page only."""
+    _, up, dec, kc, k1, np_pages = _roundtrip(rng, 3, 1)
+    # position 5 -> page tables[0][1]=1, offset 1
+    np.testing.assert_array_equal(np.asarray(up.k[1, :, 1, 1]),
+                                  np.asarray(kc[0, 5]))
+    np.testing.assert_array_equal(np.asarray(dec.k[1, :, 1, 1]),
                                   np.asarray(k1[0, 0]))
-    np.testing.assert_array_equal(np.asarray(dec.k[:, np_pages, 0]),
+    np.testing.assert_array_equal(np.asarray(dec.k[1, :, np_pages, 0]),
                                   np.asarray(k1[1, 0]))
+    assert list(np.asarray(dec.lengths)) == [6, 0]
+
+
+@pytest.mark.parametrize("li", [0, 1, 2])
+def test_paged_update_lands_in_layer(rng, li):
+    """On a stack of three layers, the prefill and decode writes of layer
+    li land in layer li alone -- an inactive slot's token in li's trash
+    page and in no other layer's -- and leave every other value as it
+    was."""
+    pool, up, dec, kc, k1, np_pages = _roundtrip(rng, 3, li)
+    before = np.asarray(pool)
+    want = before.copy()
+    for pos in range(6):                     # table [3, 1, 0], page 4
+        want[li, :, [3, 1][pos // 4], pos % 4] = np.asarray(kc[0, pos])
+    np.testing.assert_array_equal(np.asarray(up.k), want)
+    np.testing.assert_array_equal(np.asarray(up.v), want)
+    want = before.copy()
+    want[li, :, 1, 1] = np.asarray(k1[0, 0])
+    want[li, :, np_pages, 0] = np.asarray(k1[1, 0])      # li's trash page
+    np.testing.assert_array_equal(np.asarray(dec.k), want)
+    np.testing.assert_array_equal(np.asarray(dec.v), want)
     assert list(np.asarray(dec.lengths)) == [6, 0]
 
 
@@ -557,53 +609,63 @@ def test_engine_defrag_under_arena_pressure(rng):
 # ---------------------------------------------------------------------------
 # chunked prefill: kernel / twin numerics
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("start,tq,h,kvh,win", [(16, 8, 4, 2, None),
-                                                (24, 11, 4, 1, 16),
-                                                (0, 7, 8, 8, None)])
-def test_paged_prefill_kernel_vs_oracle(rng, start, tq, h, kvh, win):
+@pytest.mark.parametrize("start,tq,h,kvh,win,n_layers", [
+    pytest.param(16, 8, 4, 2, None, 1, id="16-8-4-2-None"),
+    pytest.param(24, 11, 4, 1, 16, 1, id="24-11-4-1-16"),
+    pytest.param(0, 7, 8, 8, None, 1, id="0-7-8-8-None"),
+    pytest.param(16, 8, 4, 2, None, 3, id="16-8-4-2-None-3layers")])
+def test_paged_prefill_kernel_vs_oracle(rng, start, tq, h, kvh, win,
+                                        n_layers):
     """The chunked-prefill Pallas kernel (interpret mode) matches the dense
     oracle on scattered, NaN-poisoned pools: a chunk of queries at
     [start, start+tq) attends exactly the live prefix, dead pages beyond
-    the frontier are skipped."""
+    the frontier are skipped. On a stack of layers, layer li's output is
+    the oracle's on layer li's values alone."""
     d, page, mp = 32, 8, 6
     lens = np.array([start + tq], np.int32)
     kc, vc, pk, pv, tables = _scattered_case(rng, 1, h, kvh, d, page, mp,
-                                             lens)
+                                             lens, n_layers=n_layers)
     q = jnp.asarray(rng.standard_normal((1, tq, h, d)), jnp.float32)
-    y = ak.paged_prefill_attention(q, jnp.asarray(pk), jnp.asarray(pv),
-                                   jnp.asarray(tables[0]), jnp.int32(start),
-                                   window=win, interpret=True)
     ln = int(lens[0])
-    yr = ref.mha_ref(q, jnp.asarray(kc[:, :ln]), jnp.asarray(vc[:, :ln]),
-                     causal=True, window=win)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
-                               rtol=2e-5, atol=2e-5)
+    for li in range(n_layers):
+        y = ak.paged_prefill_attention(q, jnp.asarray(pk), jnp.asarray(pv),
+                                       jnp.asarray(tables[0]),
+                                       jnp.int32(start), jnp.int32(li),
+                                       window=win, interpret=True)
+        yr = ref.mha_ref(q, jnp.asarray(kc[li, :, :ln]),
+                         jnp.asarray(vc[li, :, :ln]), causal=True, window=win)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_paged_prefill_xla_twin_bitwise(rng):
     """The explicit-gather XLA twin is bit-identical to the single-pass
-    blockwise path for a continuation chunk's rows: same KV blocking
-    anchored at 0, same op staging (the serve_decode exact-match gate with
-    chunking on rests on this)."""
+    blockwise path for a continuation chunk's rows, in every layer of a
+    stack: same KV blocking anchored at 0, same op staging (the
+    serve_decode exact-match gate with chunking on rests on this)."""
     h, kvh, d, page, mp, start, tq = 4, 2, 16, 8, 4, 13, 9
+    n_layers = 3
     lens = np.array([start + tq], np.int32)
     kc, vc, pk, pv, tables = _scattered_case(rng, 1, h, kvh, d, page, mp,
-                                             lens, poison=0.0)
+                                             lens, poison=0.0,
+                                             n_layers=n_layers)
     q = jnp.asarray(rng.standard_normal((1, tq, h, d)), jnp.float32)
-    cache = mattn.PagedKVCache(jnp.asarray(pk), jnp.asarray(pv),
-                               jnp.asarray(tables),
-                               jnp.asarray(lens), page)
-    y = mattn.paged_prefill_attention_xla(q, cache, jnp.int32(start),
-                                          window=8)
     # the single-pass reference: full-prefix blockwise, rows [start, ...)
     ln = int(lens[0])
     qfull = jnp.asarray(
         np.concatenate([rng.standard_normal((1, start, h, d)),
                         np.asarray(q)], axis=1), jnp.float32)
-    yf = mattn.blockwise_attention_xla(qfull, jnp.asarray(kc[:, :ln]),
-                                       jnp.asarray(vc[:, :ln]), causal=True,
-                                       window=8)
-    np.testing.assert_array_equal(np.asarray(y), np.asarray(yf[:, start:]))
+    for li in range(n_layers):
+        cache = mattn.PagedKVCache(jnp.asarray(pk), jnp.asarray(pv),
+                                   jnp.asarray(tables), jnp.asarray(lens),
+                                   page, jnp.int32(li))
+        y = mattn.paged_prefill_attention_xla(q, cache, jnp.int32(start),
+                                              window=8)
+        yf = mattn.blockwise_attention_xla(qfull, jnp.asarray(kc[li, :, :ln]),
+                                           jnp.asarray(vc[li, :, :ln]),
+                                           causal=True, window=8)
+        np.testing.assert_array_equal(np.asarray(y),
+                                      np.asarray(yf[:, start:]))
 
 
 def test_chunked_ssm_state_continuity(rng):

@@ -83,22 +83,25 @@ def _flash():
 
 
 def _pool():
-    return ((HEADS, N_PAGES + 1, PAGE, HEAD_DIM), jnp.bfloat16)
+    """Two layers' pools, stacked as the paged steps carry them."""
+    return ((2, HEADS, N_PAGES + 1, PAGE, HEAD_DIM), jnp.bfloat16)
 
 
 def _paged_decode():
-    def f(q, kp, vp, bt, ln):
-        return ops.paged_attention_impl(q, kp, vp, bt, ln, backend="pallas")
+    def f(q, kp, vp, bt, ln, layer):
+        return ops.paged_attention_impl(q, kp, vp, bt, ln, layer,
+                                        backend="pallas")
     return f, [((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16), _pool(), _pool(),
-               ((SLOTS, MAX_PAGES), jnp.int32), ((SLOTS,), jnp.int32)]
+               ((SLOTS, MAX_PAGES), jnp.int32), ((SLOTS,), jnp.int32),
+               ((), jnp.int32)]
 
 
 def _paged_prefill_chunk():
-    def f(q, kp, vp, bt, start):
+    def f(q, kp, vp, bt, start, layer):
         return ops.paged_prefill_attention_impl(
-            q, kp, vp, bt, start, kv_pages=8, backend="pallas")
+            q, kp, vp, bt, start, layer, kv_pages=8, backend="pallas")
     return f, [((1, PAGE, HEADS, HEAD_DIM), jnp.bfloat16), _pool(), _pool(),
-               ((MAX_PAGES,), jnp.int32), ((), jnp.int32)]
+               ((MAX_PAGES,), jnp.int32), ((), jnp.int32), ((), jnp.int32)]
 
 
 def _ssd():
@@ -131,3 +134,74 @@ def test_kernel_compiles_for_v5e(case, one_chip):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+# -- the paged steps keep the KV arena in place -----------------------------
+# qwen1.5-4b at two layers, 8 slots of 1088 positions at page 64; the
+# arena's size is the only thing that differs between the two compiles
+STEP_LAYERS, STEP_SLOTS, STEP_MAX_PAGES, STEP_KV_PAGES = 2, 8, 17, 6
+
+
+def _step_program(step, n_pages, one_chip):
+    """``step``'s jitted program, as the serving engine builds it (the
+    state donated), compiled for one described v5e chip."""
+    import dataclasses
+
+    from repro import configs
+    from repro.core.context import ExecutionContext
+    from repro.models import transformer as tf
+
+    mc = dataclasses.replace(configs.get("qwen1.5-4b"),
+                             n_layers=STEP_LAYERS)
+    ctx = ExecutionContext(cfg=_ENGINE, backend="pallas")
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: tf.init_params(jax.random.PRNGKey(0), mc)))
+    state = jax.tree.map(spec, jax.eval_shape(
+        lambda: tf.init_paged_state(mc, STEP_SLOTS, n_pages, PAGE,
+                                    STEP_MAX_PAGES)))
+    i32 = jnp.int32
+    if step == "decode":
+        fn = jax.jit(lambda p, tok, st, act: tf.paged_decode_step(
+            ctx, p, mc, tok, st, act, page_size=PAGE), donate_argnums=(2,))
+        args = (params, spec(jax.ShapeDtypeStruct((STEP_SLOTS, 1), i32)),
+                state, spec(jax.ShapeDtypeStruct((STEP_SLOTS,), jnp.bool_)))
+    else:
+        fn = jax.jit(lambda p, tok, st, slot, pages, start:
+                     tf.paged_prefill_chunk(
+                         ctx, p, mc, tok, st, slot, pages, start,
+                         page_size=PAGE, kv_pages=STEP_KV_PAGES),
+                     donate_argnums=(2,))
+        args = (params, spec(jax.ShapeDtypeStruct((1, PAGE), i32)), state,
+                spec(jax.ShapeDtypeStruct((), i32)),
+                spec(jax.ShapeDtypeStruct((STEP_MAX_PAGES,), i32)),
+                spec(jax.ShapeDtypeStruct((), i32)))
+    return fn.lower(*args).compile()
+
+
+@pytest.mark.parametrize("step", ["chunk", "decode"])
+def test_paged_step_keeps_arena_in_place(step, one_chip):
+    """The paged steps write and read the stacked KV pools where they lie:
+    the step's scratch does not grow with the arena, and no instruction
+    copies or allocates a stacked pool or materializes one layer's pool."""
+    small = _step_program(step, 56, one_chip)
+    big = _step_program(step, 112, one_chip)
+    grown = (big.memory_analysis().temp_size_in_bytes
+             - small.memory_analysis().temp_size_in_bytes)
+    assert abs(grown) < 4e6, f"scratch grows with the arena by {grown} B"
+
+    stack = f"bf16[{STEP_LAYERS},{HEADS},113,{PAGE},{HEAD_DIM}]"
+    layer = f"bf16[{HEADS},113,{PAGE},{HEAD_DIM}]"
+    offenders = []
+    for line in big.as_text().splitlines():
+        _, eq, rhs = line.partition(" = ")
+        if not eq:
+            continue
+        if rhs.startswith(layer + "{") or (
+                rhs.startswith(stack + "{") and (
+                    " copy(" in rhs or '"AllocateBuffer"' in rhs)):
+            offenders.append(line.strip()[:160])
+    assert not offenders, "\n".join(offenders)

@@ -408,8 +408,8 @@ def _paged_attention(ctx: ExecutionContext, q, k_pool, v_pool, block_tables,
     with ctx._tune_scope():
         return ctx._shard_call(
             lambda qq, bt, ln: ops.paged_attention_impl(
-                qq, k_pool, v_pool, bt, ln, layer, backend=ctx.impl_backend,
-                **kw),
+                qq, k_pool, v_pool, bt, ln, layer, cfg=ctx.cfg,
+                backend=ctx.impl_backend, **kw),
             (q, block_tables, lengths), (True, True, True))
 
 

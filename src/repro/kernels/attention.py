@@ -37,7 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.contracts import kernel_contract
+from repro.core.config import GemminiConfig
+from repro.kernels.contracts import kernel_contract, paged_heads_per_block
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -300,10 +301,10 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 # ---------------------------------------------------------------------------
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          m_ref, l_ref, acc_ref, *, npages: int, page: int,
-                         window: Optional[int], softcap: Optional[float],
-                         scale: float):
+                         page_axis: int, window: Optional[int],
+                         softcap: Optional[float], scale: float):
     b = pl.program_id(0)
-    j = pl.program_id(2)                 # logical page index within the seq
+    j = pl.program_id(page_axis)         # logical page index within the seq
 
     @pl.when(j == 0)
     def _init():
@@ -324,32 +325,35 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (rep, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (page, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        # Every head of the block at once: q (hb, rep, D) against the
+        # page's K and V (hb, page, D), two MXU products batched over the
+        # heads. q.K^T takes the stored operands and accumulates in f32
+        # (their products are exact there), so the scale follows the
+        # product; the softmax and P.V stay in f32.
+        s = jax.lax.dot_general(q_ref[0], k_ref[:, 0],
+                                (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
-        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         mask = kpos <= pos
         if window is not None:
             mask &= kpos > pos - window
         s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                                  # (hb, rep)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+        acc_ref[...] = acc_ref[...] * corr[..., None] + jax.lax.dot_general(
+            p, v_ref[:, 0].astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(j == npages - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-37)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
 
 
 @kernel_contract("paged_decode_attention")
@@ -359,6 +363,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            window: Optional[int] = None,
                            softcap: Optional[float] = None,
                            scale: Optional[float] = None,
+                           cfg: Optional[GemminiConfig] = None,
                            interpret: bool = False) -> jnp.ndarray:
     """Single-token decode against a *paged* KV cache.
 
@@ -368,18 +373,23 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     mapping request positions [j*page, (j+1)*page) to pool page
     ``block_tables[b, j]``; lengths: (B,) int32 live tokens per request
     (the current token included -- write the KV of the new token first,
-    then attend).
+    then attend); cfg: the VMEM budgets (``GemminiConfig()``'s when None).
 
-    The gather happens *inside* the kernel: each (b, kvh, j) grid step's
-    K/V BlockSpec index map reads the block table (scalar-prefetched into
-    SMEM) and DMAs exactly one page of layer ``layer`` into VMEM -- neither
-    a request's pages nor a layer's pool is ever materialized in HBM, which
-    is the whole point of paging. The stacks enter as their free
-    ``(L*KVH, NP, page, D)`` view and ``layer`` rides at the end of the
-    prefetched lengths, so the row a step reads is ``layer*KVH + kvh``.
-    Dead logical pages (j past the request frontier) clamp their index map
-    to the last live page, so Mosaic's block-revisiting elides the re-copy,
-    and the ``block_live`` predicate skips their compute.
+    The gather happens *inside* the kernel: each grid step's K/V BlockSpec
+    index map reads the block table (scalar-prefetched into SMEM) and DMAs
+    one page of layer ``layer`` for a block of KV heads into VMEM --
+    neither a request's pages nor a layer's pool is ever materialized in
+    HBM, which is the whole point of paging. The block holds every KV head
+    where its double-buffered K and V pages fit the scratchpad
+    (``contracts.paged_heads_per_block``), so the grid is (b, j); else the
+    largest divisor of KVH that fits, and the grid is (b, g, j). The
+    stacks enter as their free ``(L*KVH, NP, page, D)`` view and ``layer``
+    rides at the end of the prefetched lengths, so a block of ``hb`` rows
+    starts at row ``layer*KVH + g*hb``: its block index is
+    ``layer*(KVH/hb) + g``. Dead logical pages (j past the request
+    frontier) clamp their index map to the last live page, so Mosaic's
+    block-revisiting elides the re-copy, and the ``block_live`` predicate
+    skips their compute.
     """
     b, tq, h, d = q.shape
     assert tq == 1
@@ -387,6 +397,10 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     mp = block_tables.shape[1]
     rep = h // kvh
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
+    hb = paged_heads_per_block(cfg or GemminiConfig(), kvh=kvh, rep=rep,
+                               page=page, d=d,
+                               itemsize=jnp.dtype(k_pool.dtype).itemsize)
+    nhb = kvh // hb
 
     qg = q[:, 0].reshape(b, kvh, rep, d)
     bt = block_tables.reshape(-1).astype(jnp.int32)          # (B*MP,)
@@ -396,31 +410,40 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     kf = k_pool.reshape(n_layers * kvh, npool, page, d)
     vf = v_pool.reshape(n_layers * kvh, npool, page, d)
 
-    def _page_index(bb, hh, j, bt_ref, len_ref):
+    grid = (b, mp) if nhb == 1 else (b, nhb, mp)
+
+    def _coords(*args):
+        # (bb, g, j, bt_ref, len_ref) whatever the grid's rank
+        return (args[0], 0, *args[1:]) if nhb == 1 else args
+
+    def _head_index(*args):
+        bb, g, _, _, _ = _coords(*args)
+        return (bb, g, 0, 0)
+
+    def _page_index(*args):
+        bb, g, j, bt_ref, len_ref = _coords(*args)
         # Clamp dead j to the request's last live page: same block index ->
         # Mosaic elides the DMA; an empty request (len 0) pins page bt[b,0].
         jmax = jnp.maximum(len_ref[bb] - 1, 0) // page
-        return (len_ref[b] * kvh + hh, bt_ref[bb * mp + jnp.minimum(j, jmax)],
-                0, 0)
+        return (len_ref[b] * nhb + g,
+                bt_ref[bb * mp + jnp.minimum(j, jmax)], 0, 0)
 
     kernel = functools.partial(_paged_decode_kernel, npages=mp, page=page,
-                               window=window, softcap=softcap, scale=sc)
+                               page_axis=len(grid) - 1, window=window,
+                               softcap=softcap, scale=sc)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, mp),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, rep, d),
-                         lambda bb, hh, j, bt_ref, len_ref: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, 1, page, d), _page_index),
-            pl.BlockSpec((1, 1, page, d), _page_index),
+            pl.BlockSpec((1, hb, rep, d), _head_index),
+            pl.BlockSpec((hb, 1, page, d), _page_index),
+            pl.BlockSpec((hb, 1, page, d), _page_index),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, rep, d),
-            lambda bb, hh, j, bt_ref, len_ref: (bb, hh, 0, 0)),
+        out_specs=pl.BlockSpec((1, hb, rep, d), _head_index),
         scratch_shapes=[
-            pltpu.VMEM((rep,), jnp.float32),
-            pltpu.VMEM((rep,), jnp.float32),
-            pltpu.VMEM((rep, d), jnp.float32),
+            pltpu.VMEM((hb, rep), jnp.float32),
+            pltpu.VMEM((hb, rep), jnp.float32),
+            pltpu.VMEM((hb, rep, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -428,7 +451,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",)),
         interpret=interpret,
     )(bt, lens, qg, kf, vf)
     return out.reshape(b, 1, h, d)

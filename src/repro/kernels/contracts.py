@@ -338,6 +338,24 @@ _PAGED_GATHER = ("K/V page index gathers through the scalar-prefetched "
                  "leaves [0, n_pages)")
 
 
+def paged_heads_per_block(cfg: GemminiConfig, *, kvh: int, rep: int,
+                          page: int, d: int, itemsize: int) -> int:
+    """KV heads one paged-decode grid step takes: all ``kvh`` where the
+    double-buffered K and V blocks fit the scratchpad and the resident
+    query, output and softmax scratch fit the accumulator; else the
+    largest divisor of ``kvh`` that fits; 1 where none does (the lint
+    then reports the overflow)."""
+    for hb in range(kvh, 0, -1):
+        if kvh % hb:
+            continue
+        streamed = cfg.pipeline_depth * 2 * hb * page * d * itemsize
+        resident = hb * rep * (d * (2 * itemsize + 4) + 2 * 4)
+        if (streamed <= cfg.scratchpad_bytes
+                and resident <= cfg.accumulator_bytes):
+            return hb
+    return 1
+
+
 @contract_builder("paged_decode_attention")
 def paged_decode_attention_contract(cfg: GemminiConfig, *, b: int, h: int,
                                     kvh: int, d: int, page: int, mp: int,
@@ -346,27 +364,36 @@ def paged_decode_attention_contract(cfg: GemminiConfig, *, b: int, h: int,
     io = _attn_dt(dtype)
     f32 = ("float", 4)
     rep = h // kvh
+    hb = paged_heads_per_block(cfg, kvh=kvh, rep=rep, page=page, d=d,
+                               itemsize=io[1])
     pool = (n_layers * kvh, n_pages, page, d)      # the stack's free view
+    if hb == kvh:
+        grid = (("bb", b), ("j", mp))
+        head_map = lambda bb, j: (bb, 0, 0, 0)              # noqa: E731
+    else:
+        grid = (("bb", b), ("g", kvh // hb), ("j", mp))
+        head_map = lambda bb, g, j: (bb, g, 0, 0)           # noqa: E731
     return KernelContract(
         name="paged_decode_attention",
-        grid=(("bb", b), ("hh", kvh), ("j", mp)),
-        semantics=("parallel", "parallel", "arbitrary"),
+        grid=grid,
+        semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",),
         scalar_prefetch=2,
         inputs=(
-            OperandSpec("q", (b, kvh, rep, d), (1, 1, rep, d),
-                        lambda bb, hh, j: (bb, hh, 0, 0), io),
-            OperandSpec("k_pool", pool, (1, 1, page, d), None, io,
+            OperandSpec("q", (b, kvh, rep, d), (1, hb, rep, d), head_map,
+                        io),
+            OperandSpec("k_pool", pool, (hb, 1, page, d), None, io,
                         data_dependent=_PAGED_GATHER, budget="scratchpad"),
-            OperandSpec("v_pool", pool, (1, 1, page, d), None, io,
+            OperandSpec("v_pool", pool, (hb, 1, page, d), None, io,
                         data_dependent=_PAGED_GATHER, budget="scratchpad"),
         ),
-        outputs=(OperandSpec("o", (b, kvh, rep, d), (1, 1, rep, d),
-                             lambda bb, hh, j: (bb, hh, 0, 0), io),),
-        scratch=(ScratchSpec("m", (rep,), f32),
-                 ScratchSpec("l", (rep,), f32),
-                 ScratchSpec("acc", (rep, d), f32)),
+        outputs=(OperandSpec("o", (b, kvh, rep, d), (1, hb, rep, d),
+                             head_map, io),),
+        scratch=(ScratchSpec("m", (hb, rep), f32),
+                 ScratchSpec("l", (hb, rep), f32),
+                 ScratchSpec("acc", (hb, rep, d), f32)),
         reductions=(Reduction("o", ("j",), via="scratch", scratch="acc"),),
-        dots=(DotContract(io, io, f32),),
+        dots=(DotContract(io, io, f32),          # q.K^T, stored operands
+              DotContract(f32, f32, f32)),       # P.V
     )
 
 

@@ -344,6 +344,7 @@ def paged_attention_impl(q, k_pool, v_pool, block_tables, lengths, layer, *,
                          window: Optional[int] = None,
                          softcap: Optional[float] = None,
                          scale: Optional[float] = None,
+                         cfg: Optional[GemminiConfig] = None,
                          backend: Backend = "xla"):
     """Single-token decode over a paged KV cache (the serving engine's hot
     loop). q: (B, 1, H, D); k_pool/v_pool: (L, KVH, NP, page, D), every
@@ -366,10 +367,12 @@ def paged_attention_impl(q, k_pool, v_pool, block_tables, lengths, layer, *,
                 under either ``gqa_grouped_decode`` setting (the engine's
                 exact-match contract); SPMD-partitionable.
     pallas /    ``kernels/attention.paged_decode_attention``: block tables
-    interpret   scalar-prefetched to SMEM, one pool page DMA'd per grid
-                step via the BlockSpec index map; dead pages clamp-elided
-                and compute-skipped (``block_live``). The grouped-decode
-                flag does not apply (the kernel is already grouped).
+    interpret   scalar-prefetched to SMEM, one pool page of every KV head
+                (or of as many as ``cfg``'s scratchpad holds) DMA'd per
+                grid step via the BlockSpec index map; dead pages
+                clamp-elided and compute-skipped (``block_live``). The
+                grouped-decode flag does not apply (the kernel is already
+                grouped).
     ==========  ===========================================================
     """
     if backend == "xla":
@@ -382,7 +385,8 @@ def paged_attention_impl(q, k_pool, v_pool, block_tables, lengths, layer, *,
     from repro.kernels import attention as attn_kernel
     return attn_kernel.paged_decode_attention(
         q, k_pool, v_pool, block_tables, lengths, layer, window=window,
-        softcap=softcap, scale=scale, interpret=(backend == "interpret"))
+        softcap=softcap, scale=scale, cfg=cfg,
+        interpret=(backend == "interpret"))
 
 
 def paged_prefill_attention_impl(q, k_pool, v_pool, block_table, start,

@@ -29,6 +29,7 @@ from typing import List, Optional
 
 from repro.core import isa
 from repro.core.config import GemminiConfig, bytes_of
+from repro.kernels.contracts import paged_heads_per_block
 from repro.tune import cache as tcache
 
 # Static defaults -- the schedules the kernels ship with when tuning is off
@@ -214,9 +215,10 @@ class PagedAttnSchedule:
 
     Unlike the other schedule spaces this one is *allocation-coupled*: the
     page size is baked into the serving engine's pool shapes at startup
-    (``serving.PagedKVAllocator``), and the kernel streams exactly one page
-    per grid step. The lattice therefore trades kernel efficiency (bigger
-    pages amortize the per-step table-read/DMA overhead) against allocator
+    (``serving.PagedKVAllocator``), and the kernel streams one page of a
+    block of KV heads per grid step. The lattice therefore trades kernel
+    efficiency (bigger pages amortize the per-step table-read/DMA overhead)
+    against allocator
     efficiency (bigger pages waste ~page/2 tokens of HBM per request to
     internal fragmentation, shrinking the number of co-resident requests).
     """
@@ -233,7 +235,8 @@ def default_paged_schedule() -> PagedAttnSchedule:
 
 def _paged_fits(cfg: GemminiConfig, page: int, rep: int, d: int,
                 in_bytes: int) -> bool:
-    # Streamed per page step: one K and one V page (double-buffered).
+    # Streamed per page step: one K and one V page of at least one KV head
+    # (double-buffered); the kernel takes more heads where they fit.
     streamed = cfg.pipeline_depth * 2 * page * d * in_bytes
     # Resident across the stream: the (rep, D) query rows + f32 accumulator
     # + (m, l) state, as kernels/attention._paged_decode_kernel holds them.
@@ -252,6 +255,9 @@ def paged_attn_cycles(sched: PagedAttnSchedule, cfg: GemminiConfig, b: int,
 
     Live pages per request follow ``attention.block_live`` with block_q=1:
     ceil(len/page) minus the pages a sliding window lets the kernel skip.
+    Each grid step takes one live page of a block of KV heads
+    (``contracts.paged_heads_per_block``), so the fixed per-step cost is
+    charged per (slot, head block, live page).
     The fragmentation penalty models the allocator side: the last page of
     every request is half-wasted on average, which at a fixed HBM budget
     evicts-or-queues proportionally more co-resident requests, so it is
@@ -278,7 +284,9 @@ def paged_attn_cycles(sched: PagedAttnSchedule, cfg: GemminiConfig, b: int,
     frag = b * kvh * (page / 2) * 2 * d * in_bytes
     bw = sys.effective_bw(cfg.dim)
     compute = max(macs / _macs_per_cycle(cfg), (loads + frag) / bw)
-    return compute + b * kvh * live * PAGE_STEP_CYCLES
+    hb = paged_heads_per_block(cfg, kvh=kvh, rep=rep, page=page, d=d,
+                               itemsize=in_bytes)
+    return compute + b * (kvh // hb) * live * PAGE_STEP_CYCLES
 
 
 def enumerate_paged_schedules(cfg: GemminiConfig, b: int, h: int, kvh: int,

@@ -2,6 +2,8 @@
 continuous-batching engine vs the static reference path, and the paged
 schedule's ride through the tuner cache."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -186,18 +188,41 @@ def _scattered_case(rng, b, h, kvh, d, page, mp, lens, poison=np.nan,
     return kc, vc, pool_k, pool_v, tables
 
 
-@pytest.mark.parametrize("h,kvh,win,cap,n_layers", [
-    pytest.param(4, 2, None, None, 1, id="4-2-None-None"),
-    pytest.param(4, 1, 24, None, 1, id="4-1-24-None"),
-    pytest.param(8, 8, None, 30.0, 1, id="8-8-None-30.0"),
-    pytest.param(4, 2, None, None, 3, id="4-2-None-None-3layers")])
-def test_paged_kernel_vs_oracle(rng, h, kvh, win, cap, n_layers):
+# partial / tiny / full; whole pages beside an empty slot
+_LENS = (37, 1, 80)
+_WHOLE_PAGES = (32, 0, 48)
+# a scratchpad that holds the K and V pages of 2 of 8 f32 heads, doubled
+_SPAD_2_HEADS = 20 * 1024
+
+
+@pytest.mark.parametrize("h,kvh,win,cap,n_layers,lens,spad", [
+    pytest.param(4, 2, None, None, 1, _LENS, None, id="4-2-None-None"),
+    pytest.param(4, 1, 24, None, 1, _LENS, None, id="4-1-24-None"),
+    pytest.param(8, 8, None, 30.0, 1, _LENS, None, id="8-8-None-30.0"),
+    pytest.param(4, 2, None, None, 3, _LENS, None,
+                 id="4-2-None-None-3layers"),
+    pytest.param(20, 20, None, None, 2, _LENS, None, id="20-20-mha"),
+    pytest.param(8, 2, None, None, 2, _LENS, None, id="8-2-gqa-rep4"),
+    pytest.param(16, 8, 24, None, 2, _LENS, _SPAD_2_HEADS,
+                 id="16-8-head-blocks"),
+    pytest.param(4, 2, None, None, 1, _WHOLE_PAGES, None,
+                 id="4-2-whole-pages-empty-slot")])
+def test_paged_kernel_vs_oracle(rng, h, kvh, win, cap, n_layers, lens, spad):
     """The Pallas paged-decode kernel (interpret mode) matches the dense
     oracle on scattered, NaN-poisoned pools: dead pages are skipped, the
     partial tail page is masked. On a stack of layers, layer li's output
-    is the oracle's on layer li's values alone."""
+    is the oracle's on layer li's values alone. A grid step takes a page
+    of every KV head, or of as many as the config's scratchpad holds (the
+    head-block case splits 8 heads into 4 blocks); an empty slot reads
+    zeros."""
+    from repro.kernels.contracts import paged_heads_per_block
     b, d, page, mp = 3, 32, 16, 5
-    lens = np.array([37, 1, 80], np.int32)       # partial / tiny / full
+    lens = np.array(lens, np.int32)
+    cfg = None if spad is None else GemminiConfig(dim=16,
+                                                  scratchpad_bytes=spad)
+    hb = paged_heads_per_block(cfg or GemminiConfig(), kvh=kvh,
+                               rep=h // kvh, page=page, d=d, itemsize=4)
+    assert hb == (2 if spad else kvh)
     kc, vc, pk, pv, tables = _scattered_case(rng, b, h, kvh, d, page, mp,
                                              lens, n_layers=n_layers)
     q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
@@ -205,9 +230,12 @@ def test_paged_kernel_vs_oracle(rng, h, kvh, win, cap, n_layers):
         y = ak.paged_decode_attention(q, jnp.asarray(pk), jnp.asarray(pv),
                                       jnp.asarray(tables), jnp.asarray(lens),
                                       jnp.int32(li), window=win, softcap=cap,
-                                      interpret=True)
+                                      cfg=cfg, interpret=True)
         for bb in range(b):
             L = int(lens[bb])
+            if L == 0:
+                np.testing.assert_array_equal(np.asarray(y[bb]), 0.0)
+                continue
             yr = ref.mha_ref(q[bb:bb + 1],
                              jnp.asarray(kc[li, bb:bb + 1, :L]),
                              jnp.asarray(vc[li, bb:bb + 1, :L]), causal=True,
@@ -902,6 +930,26 @@ def test_paged_schedule_lattice_legal():
     c2 = schedules.paged_attn_cycles(cands[0], cfg, 4, 8, 2, 64, 2048,
                                      window=None, in_bytes=2)
     assert c1 <= c2
+
+
+def test_paged_cycles_charge_per_head_block():
+    """The fixed per-step cost is charged per (slot, head block, live
+    page): a scratchpad that holds only 4 of 8 heads' K and V pages,
+    double-buffered, costs the model 2 steps a live page where every head
+    fits in one."""
+    from repro.tune import schedules
+    sched = schedules.default_paged_schedule()
+    whole = GemminiConfig(input_dtype="bf16", acc_dtype="fp32",
+                          output_dtype="bf16")
+    b, kvh, d, page = 4, 8, 64, 64
+    four_heads = 2 * 2 * 4 * page * d * 2         # buffers x (K, V) x bf16
+    split = dataclasses.replace(whole, scratchpad_bytes=four_heads)
+    live = 1024 // page                            # mean_len 2048 / 2
+    cost = {name: schedules.paged_attn_cycles(
+        sched, cfg, b, kvh, kvh, d, 2048, window=None, in_bytes=2)
+        for name, cfg in (("whole", whole), ("split", split))}
+    assert cost["split"] - cost["whole"] == pytest.approx(
+        b * (2 - 1) * live * schedules.PAGE_STEP_CYCLES)
 
 
 def test_paged_schedule_cache_roundtrip(tmp_cache):

@@ -13,6 +13,7 @@ runs these tests loads the TPU compiler, and every pytest worker collects
 the same tests.
 """
 
+import math
 import os
 
 import jax
@@ -134,6 +135,31 @@ def test_kernel_compiles_for_v5e(case, one_chip):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+def test_paged_decode_contract_matches_kernel():
+    """At the decode case's shape one grid step takes a page of every KV
+    head: the contract's grid is (slot, page), B x MP steps with no head
+    axis, and it is the grid and blocks the kernel launches with."""
+    from repro.kernels import contracts as kc
+
+    c = kc.paged_decode_attention_contract(
+        _ENGINE, b=SLOTS, h=HEADS, kvh=HEADS, d=HEAD_DIM, page=PAGE,
+        mp=MAX_PAGES, n_pages=N_PAGES + 1, n_layers=2)
+    assert [n for n, _ in c.grid] == ["bb", "j"]
+    assert math.prod(n for _, n in c.grid) == SLOTS * MAX_PAGES
+
+    _, shapes = _paged_decode()
+    jaxpr = jax.make_jaxpr(lambda *a: ops.paged_attention_impl(
+        *a, cfg=_ENGINE, backend="interpret"))(
+        *[jax.ShapeDtypeStruct(sh, dt) for sh, dt in shapes])
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    gm = call.params["grid_mapping"]
+    assert tuple(gm.grid) == tuple(n for _, n in c.grid)
+    blocks = [tuple(getattr(x, "block_size", x) for x in bm.block_shape)
+              for bm in gm.block_mappings]
+    assert blocks == [op.block for op in c.inputs + c.outputs]
 
 
 # -- the paged steps keep the KV arena in place -----------------------------

@@ -1,5 +1,7 @@
-"""Roofline table: aggregates results/dryrun/*.json into the EXPERIMENTS.md
-section-Roofline table (one row per arch x shape x mesh x variant)."""
+"""Roofline table: aggregates the dry run's results/dryrun/*.json
+(``python -m repro.launch.dryrun``) into one row per arch x shape x mesh x
+variant. The terms are compile-time cost-analysis estimates, not
+timings."""
 
 from __future__ import annotations
 

@@ -359,20 +359,17 @@ def paged_attention_impl(q, k_pool, v_pool, block_tables, lengths, layer, *,
     ``repro.tune.resolve_paged_attn_schedule``, not resolved per call (a
     pool cannot be re-blocked mid-flight).
 
-    backend matrix (``gqa_grouped_decode`` flag applies to xla only):
+    backend matrix:
 
     ==========  ===========================================================
     xla         ``paged_decode_attention_xla``: explicit block-table
                 gather, bit-identical to the dense ``decode_attention``
-                under either ``gqa_grouped_decode`` setting (the engine's
-                exact-match contract); SPMD-partitionable.
+                (the engine's exact-match contract); SPMD-partitionable.
     pallas /    ``kernels/attention.paged_decode_attention``: block tables
     interpret   scalar-prefetched to SMEM, one pool page of every KV head
                 (or of as many as ``cfg``'s scratchpad holds) DMA'd per
                 grid step via the BlockSpec index map; dead pages
-                clamp-elided and compute-skipped (``block_live``). The
-                grouped-decode flag does not apply (the kernel is already
-                grouped).
+                clamp-elided and compute-skipped (``block_live``).
     ==========  ===========================================================
     """
     if backend == "xla":
@@ -427,8 +424,9 @@ def paged_prefill_attention_impl(q, k_pool, v_pool, block_table, start,
     xla         explicit gather + ``blockwise_attention_xla`` with the same
                 KV blocking anchored at position 0 as the single-pass
                 prefill path -- bit-identical to the whole-prompt pass for
-                the overlapping rows (the serve_decode exact-match gate
-                with chunking enabled relies on this).
+                the overlapping rows (the exact-match gate in
+                tests/test_serving_families.py, which chunks its
+                prompts, relies on this).
     pallas /    ``kernels/attention.paged_prefill_attention``: block table
     interpret   scalar-prefetched to SMEM, grid (H, nq, pages), one pool
                 page DMA'd per step via the BlockSpec index map; dead pages

@@ -7,7 +7,7 @@ For each cell this driver builds the production mesh, the sharded
 ShapeDtypeStruct inputs, jits the right step (train / prefill / serve),
 ``.lower().compile()``s it, prints ``memory_analysis()`` /
 ``cost_analysis()``, and dumps the roofline terms to a JSON results file
-consumed by EXPERIMENTS.md and benchmarks/.
+that ``python -m paper.run`` tabulates (paper/bench_roofline.py).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma3-4b \

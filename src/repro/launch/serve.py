@@ -4,8 +4,8 @@ Requests run through ``repro.serving.ServingEngine``: paged KV cache,
 admission queue, prefill/decode interleaving, preemption under cache
 pressure -- the request-level system layer (docs/serving.md). The old
 static batch loop survives as ``--policy static`` (admission barrier, no
-slot recycling) for A/B comparison; ``benchmarks/bench_serving.py`` tracks
-the two policies against each other per CI run.
+slot recycling) for A/B comparison. Speed is measured on the chip by
+``bench/run.py`` (PERF.md), not by this CLI.
 
   PYTHONPATH=src python -m repro.launch.serve --arch mamba2-1.3b --smoke \
       --batch 4 --prompt-len 32 --gen 32

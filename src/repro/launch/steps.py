@@ -93,7 +93,7 @@ def make_prefill_step(engine: GemminiInstance, cfg: tf.ModelConfig, mesh,
     """Inference prefill: forward over the prompt, return last-token logits.
 
     (Roofline-wise prefill == forward; the cache write is a minor term and is
-    exercised by the serving example, examples/serve_decode.py.)
+    exercised by the serving gate, tests/test_serving_families.py.)
     """
     res_shd = shd.to_named(shd.residual_spec(cfg, mesh, batch, seq), mesh)
     log_shd = shd.to_named(shd.logits_spec(cfg, mesh, batch), mesh)
@@ -110,7 +110,8 @@ def make_prefill_step(engine: GemminiInstance, cfg: tf.ModelConfig, mesh,
 
 def make_serve_step(engine: GemminiInstance, cfg: tf.ModelConfig, mesh,
                     batch: int, max_seq: int):
-    """One-token decode against a KV/SSM cache of ``max_seq``."""
+    """One-token decode against a dense KV/SSM cache of ``max_seq``: the
+    oracle's ``decode_step``, not the serving engine's paged step."""
 
     def serve_step(params, tokens, state: tf.DecodeState):
         logits, new_state = tf.decode_step(engine, params, cfg, tokens,

@@ -182,7 +182,6 @@ def decode_attention(q, cache: KVCache, pos, *,
     Works with a sequence-sharded cache: the masked einsum contracts the full
     S axis; XLA inserts the partial-softmax all-reduce.
     """
-    from repro.core import flags
     b, tq, h, d = q.shape
     _, s, kvh, _ = cache.k.shape
     rep = h // kvh
@@ -191,26 +190,6 @@ def decode_attention(q, cache: KVCache, pos, *,
     mask = kpos <= pos
     if window is not None:
         mask = mask & (kpos > pos - window)
-
-    if flags.get("gqa_grouped_decode"):
-        # grouped GQA: no repeat -- K/V keep their (B, S, KVH, D) layout,
-        # their sequence sharding, AND their bf16 storage dtype end to end
-        # (an astype(f32) here makes XLA materialize a full f32 copy of the
-        # cache -- measured 12 GB/device/token; instead the dots accumulate
-        # in f32 via preferred_element_type, MXU-style). The softmax
-        # reduction over the sharded S axis is the only cross-shard
-        # communication: an all-reduce of (B, KVH, rep[, D]) scalars.
-        qg = (q[:, 0].reshape(b, kvh, rep, d).astype(jnp.float32)
-              * sc).astype(cache.k.dtype)
-        sl = jnp.einsum("bgrd,bsgd->bgrs", qg, cache.k,
-                        preferred_element_type=jnp.float32)
-        if softcap is not None:
-            sl = softcap * jnp.tanh(sl / softcap)
-        sl = jnp.where(mask[None, None, None], sl, _NEG_INF)
-        p = jax.nn.softmax(sl, axis=-1)
-        out = jnp.einsum("bgrs,bsgd->bgrd", p.astype(cache.v.dtype),
-                         cache.v, preferred_element_type=jnp.float32)
-        return out.reshape(b, 1, h, d).astype(q.dtype)
 
     kh = jnp.repeat(cache.k, rep, axis=2)
     vh = jnp.repeat(cache.v, rep, axis=2)
@@ -225,28 +204,8 @@ def decode_attention(q, cache: KVCache, pos, *,
 
 
 def update_cache(cache: KVCache, k_new, v_new, pos) -> KVCache:
-    """Insert (B, T, KVH, D) at positions [pos, pos+T) of the cache.
-
-    Two lowerings, selected by the ``onehot_cache_update`` flag:
-
-    * dynamic-update-slice (baseline). On a *sequence-sharded* cache the
-      SPMD partitioner cannot prove the dynamic write stays within one
-      shard, so it all-gathers the whole cache, updates, and re-slices --
-      ~2x the cache size in collective bytes PER DECODED TOKEN (measured:
-      111.7 GB/device for gemma2-2b @ 500k).
-    * one-hot select (optimized): ``where(iota == pos, new, cache)`` is
-      elementwise over the sequence axis, so every shard updates locally;
-      no collective at all. Costs one full local cache read+write, which
-      decode already pays to attend.
-    """
-    from repro.core import flags
-    t = k_new.shape[1]
-    if flags.get("onehot_cache_update") and t == 1:
-        s = cache.k.shape[1]
-        hit = (jax.lax.broadcasted_iota(jnp.int32, (1, s, 1, 1), 1) == pos)
-        k = jnp.where(hit, k_new.astype(cache.k.dtype), cache.k)
-        v = jnp.where(hit, v_new.astype(cache.v.dtype), cache.v)
-        return KVCache(k, v)
+    """Insert (B, T, KVH, D) at positions [pos, pos+T) of the cache
+    (a dynamic-update-slice of the sequence axis)."""
     k = jax.lax.dynamic_update_slice(cache.k, k_new.astype(cache.k.dtype),
                                      (0, pos, 0, 0))
     v = jax.lax.dynamic_update_slice(cache.v, v_new.astype(cache.v.dtype),
@@ -332,12 +291,11 @@ def paged_decode_attention_xla(q, cache: PagedKVCache, *,
     ``cache.layer`` of the stacked pools inside its one gather, so no
     layer's pool is sliced out first. Numerics mirror
     ``decode_attention`` exactly -- same einsums, same staging, same
-    mask-then-softmax, including the ``gqa_grouped_decode`` flag branch --
-    so a request decoded through the paged path is bit-identical to the
-    dense static path under either flag setting (the serve_decode
-    example's mismatch gate relies on this).
+    mask-then-softmax -- so a request decoded through the paged path is
+    bit-identical to the dense-cache oracle (``transformer.decode_step``);
+    the exact-match gate in ``tests/test_serving_families.py`` relies on
+    this.
     """
-    from repro.core import flags
     b, tq, h, d = q.shape
     _, kvh, _, page, _ = cache.k.shape
     rep = h // kvh
@@ -355,23 +313,6 @@ def paged_decode_attention_xla(q, cache: PagedKVCache, *,
     mask = kpos[None, :] <= pos
     if window is not None:
         mask = mask & (kpos[None, :] > pos - window)
-
-    if flags.get("gqa_grouped_decode"):
-        # The dense path's no-repeat/bf16-storage contraction (see
-        # decode_attention): K/V stay at storage dtype, dots accumulate
-        # f32 via preferred_element_type.
-        kg, vg = gather(cache.k), gather(cache.v)
-        qg = (q[:, 0].reshape(b, kvh, rep, d).astype(jnp.float32)
-              * sc).astype(kg.dtype)
-        sl = jnp.einsum("bgrd,bsgd->bgrs", qg, kg,
-                        preferred_element_type=jnp.float32)
-        if softcap is not None:
-            sl = softcap * jnp.tanh(sl / softcap)
-        sl = jnp.where(mask[:, None, None, :], sl, _NEG_INF)
-        p = jax.nn.softmax(sl, axis=-1)
-        out = jnp.einsum("bgrs,bsgd->bgrd", p.astype(vg.dtype), vg,
-                         preferred_element_type=jnp.float32)
-        return out.reshape(b, 1, h, d).astype(q.dtype)
 
     kh = jnp.repeat(gather(cache.k), rep, axis=2)
     vh = jnp.repeat(gather(cache.v), rep, axis=2)

@@ -151,7 +151,7 @@ def unembed_apply(engine: GemminiInstance, table: jnp.ndarray,
 
 # ---------------------------------------------------------------------------
 # causal depthwise conv1d (mamba2 prefix conv; also the depthwise op the
-# paper assigns to the host -- see benchmarks/bench_system_amdahl.py)
+# paper assigns to the host -- see paper/bench_system_amdahl.py)
 # ---------------------------------------------------------------------------
 def causal_conv1d(x: jnp.ndarray, w: jnp.ndarray,
                   state: Optional[jnp.ndarray] = None

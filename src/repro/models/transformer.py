@@ -569,7 +569,11 @@ def loss_fn(engine, params, cfg: ModelConfig, tokens, labels,
 
 
 # ---------------------------------------------------------------------------
-# decode (serve_step)
+# dense-cache decode: the one-request oracle. One contiguous (B, S) cache
+# and one shared position; the paged engine's greedy tokens are checked
+# against it token for token (tests/test_serving*.py), and the dry run's
+# decode shapes lower it (launch/steps.make_serve_step). The engine itself
+# runs the paged path below.
 # ---------------------------------------------------------------------------
 class DecodeState(NamedTuple):
     kv_k: Optional[jnp.ndarray]       # (L, B, S, KVH, D) or None
@@ -601,7 +605,8 @@ def prefill_into_cache(engine: GemminiInstance, params: Params,
                        state: DecodeState,
                        extra_embeds: Optional[jnp.ndarray] = None
                        ) -> Tuple[jnp.ndarray, DecodeState]:
-    """Forward over the prompt writing KV/SSM caches at positions [0, P).
+    """The oracle's prefill: forward over the prompt writing the dense
+    KV/SSM caches at positions [0, P).
 
     tokens: (B, P) [or (B, P, n_q)]. Returns (logits (B, P', V), state with
     ``pos`` = number of cached positions = the next write position).
@@ -642,8 +647,9 @@ def prefill_into_cache(engine: GemminiInstance, params: Params,
 def decode_step(engine: GemminiInstance, params: Params, cfg: ModelConfig,
                 tokens: jnp.ndarray, state: DecodeState
                 ) -> Tuple[jnp.ndarray, DecodeState]:
-    """One serving step: tokens (B, 1) [or (B, 1, n_q)] with a KV/SSM cache
-    of ``max_seq``; returns logits for the new token and the updated state."""
+    """The oracle's decode step: tokens (B, 1) [or (B, 1, n_q)] with a dense
+    KV/SSM cache of ``max_seq``; returns logits for the new token and the
+    updated state."""
     if cfg.n_codebooks > 1:
         h = sum(layers.embed_apply(params["embed"][i], tokens[..., i])
                 for i in range(cfg.n_codebooks))
@@ -655,72 +661,6 @@ def decode_step(engine: GemminiInstance, params: Params, cfg: ModelConfig,
     positions = jnp.broadcast_to(pos[None, None], (b, 1))
     windows = jnp.asarray(layer_windows(cfg, 0))
     bases = jnp.asarray(layer_rope_bases(cfg))
-
-    from repro.core import flags
-    if flags.get("decode_unroll"):
-        win_np = layer_windows(cfg, 0)
-        base_np = layer_rope_bases(cfg)
-        kv_k, kv_v = state.kv_k, state.kv_v
-        conv, st = state.conv, state.ssm
-        for i in range(cfg.n_layers):
-            bp = jax.tree.map(lambda p: p[i], params["blocks"])
-            kvc = attn.KVCache(kv_k[i], kv_v[i]) \
-                if kv_k is not None else None
-            ssc = ssm.SSMCache(conv[i], st[i]) if conv is not None else None
-            h, kvc, ssc = _block_apply(
-                engine, cfg, bp, h, positions,
-                jnp.int32(int(win_np[i])), float(base_np[i]),
-                kv_cache=kvc, ssm_cache=ssc, cache_pos=pos)
-            if kvc is not None:
-                kv_k = kv_k.at[i].set(kvc.k.astype(kv_k.dtype))
-                kv_v = kv_v.at[i].set(kvc.v.astype(kv_v.dtype))
-            if ssc is not None:
-                conv = conv.at[i].set(ssc.conv.astype(conv.dtype))
-                st = st.at[i].set(ssc.state.astype(st.dtype))
-        logits = unembed(engine, cfg, params, h)
-        return logits, DecodeState(kv_k, kv_v, conv, st, pos + 1)
-
-    if flags.get("cache_as_carry"):
-        # carry the stacked caches; slice layer li in, DUS the update back
-        # in place. XLA's in-place dynamic-update-slice fusion keeps the
-        # carry aliased, so per layer only the layer's slice moves.
-        def body_c(carry, xs):
-            h, kv_k, kv_v, conv, st = carry
-            bp, win, base, li = xs
-
-            def sl(stack):
-                if stack is None:
-                    return None
-                s = jax.lax.dynamic_index_in_dim(stack, li, 0,
-                                                 keepdims=False)
-                return s
-
-            def up(stack, new):
-                if stack is None:
-                    return None
-                return jax.lax.dynamic_update_index_in_dim(
-                    stack, new.astype(stack.dtype), li, 0)
-
-            kvc = attn.KVCache(sl(kv_k), sl(kv_v)) \
-                if kv_k is not None else None
-            ssc = ssm.SSMCache(sl(conv), sl(st)) \
-                if conv is not None else None
-            h, kvc, ssc = _block_apply(engine, cfg, bp, h, positions, win,
-                                       base, kv_cache=kvc, ssm_cache=ssc,
-                                       cache_pos=pos)
-            carry = (h,
-                     up(kv_k, kvc.k) if kvc else None,
-                     up(kv_v, kvc.v) if kvc else None,
-                     up(conv, ssc.conv) if ssc else None,
-                     up(st, ssc.state) if ssc else None)
-            return carry, None
-
-        xs = (params["blocks"], windows, bases,
-              jnp.arange(cfg.n_layers, dtype=jnp.int32))
-        (h, kv_k, kv_v, conv, st), _ = jax.lax.scan(
-            body_c, (h, state.kv_k, state.kv_v, state.conv, state.ssm), xs)
-        logits = unembed(engine, cfg, params, h)
-        return logits, DecodeState(kv_k, kv_v, conv, st, pos + 1)
 
     def body(h, xs):
         bp, win, base, kv_k, kv_v, conv, st = xs

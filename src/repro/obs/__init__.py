@@ -10,7 +10,7 @@ Two cooperating pieces (docs/observability.md):
   spans, which land as ``jax.profiler`` annotations on the device trace's
   clock and as timed registry observations.
 - :mod:`repro.obs.metrics` — labelled counters/gauges/histograms; the
-  one schema behind ``engine.summarize()`` and BENCH_serving rows.
+  one schema behind ``engine.summarize()`` and the trace's counter tracks.
 
 ``python -m repro.obs <trace.json>`` summarizes an exported trace;
 ``serve --profile DIR`` writes a profiler session with the device ops and

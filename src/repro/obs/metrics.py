@@ -1,8 +1,7 @@
 """Labelled metrics registry: counters, gauges, histograms.
 
 Replaces the serving engine's ad-hoc ``self.counters`` dict with one
-schema that feeds ``summarize()``, ``BENCH_serving.json`` rows, and the
-tracer's counter tracks.  Everything is plain-Python and allocation-light
+schema that feeds ``summarize()`` and the tracer's counter tracks.  Everything is plain-Python and allocation-light
 so the registry can sit on the engine hot path.
 
 Identity model: a metric is ``(name, frozenset(labels.items()))``.

@@ -14,8 +14,7 @@ page -- and prefill pads prompts up to a page-size multiple so distinct
 prompt lengths share compile-cache buckets. The *contents* are fully
 dynamic: requests enter and leave slots every iteration, which is exactly
 the contention the static batch loop (``policy="static"``: admission
-barrier, no slot recycling) cannot express; ``benchmarks/bench_serving.py``
-measures the two policies against each other on one request trace.
+barrier, no slot recycling) cannot express.
 
 Chunked prefill (``prefill_chunk``) extends the discipline to long
 prompts: fixed-size chunks are their own compile buckets (the traced
@@ -26,8 +25,8 @@ state, and only the final chunk samples a token.
 Per-request numerics are batch-invariant: projections, norms, and the
 paged attention path are row-independent, so a request decoded alongside
 arbitrary co-tenants produces bit-identical tokens to the same request
-decoded alone through the static reference path (``examples/serve_decode``
-gates its exit code on this).
+decoded alone through the dense-cache oracle (``transformer.decode_step``;
+tests/test_serving_families.py gates on this).
 
 Control-plane / compute split: every *decision* the engine makes --
 admission, chunk ordering, preemption, recovery-ladder control flow,
@@ -67,8 +66,8 @@ from repro.serving.scheduler import ContinuousScheduler, Request, summarize
 
 # Jitted step functions shared across ServingEngine instances: jax.jit
 # caches per function object, so per-engine lambdas would recompile every
-# prefill/decode bucket on every engine construction (e.g. the
-# static-vs-continuous benchmark builds four engines over one model).
+# prefill/decode bucket on every engine construction (e.g. a policy A/B
+# builds several engines over one model).
 # Keyed by everything the closures bake in; both configs are frozen
 # dataclasses, so the key is value-hashed, not identity-hashed.
 _JIT_CACHE: Dict = {}
@@ -610,7 +609,7 @@ class EngineControlPlane:
         # token count (slot recycling), independent of host noise.
         summary["iterations"] = float(iters)
         # Robustness counters (all 0 on a fault-free engine) + step-latency
-        # percentiles from the watchdog: the BENCH_serving robustness row.
+        # percentiles from the watchdog.
         # Counters read from the metrics registry (labels aggregated);
         # occupancy gauges contribute their run peaks (*_peak keys).
         summary["retries"] = self.metrics.value("retries")

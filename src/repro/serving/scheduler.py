@@ -40,7 +40,7 @@ status at the admission and decode-step boundaries (:meth:`shed_expired`)
 ordering policy (PR 5 behavior).
 
 Telemetry is per-request (TTFT, end-to-end latency, preemption count) and
-aggregated to the p50/p99 + tokens/s numbers BENCH_serving.json tracks.
+aggregated by :func:`summarize` into p50/p99 latencies and tokens/s.
 """
 
 from __future__ import annotations
@@ -774,7 +774,7 @@ def _pct(vals: List[float], p: float) -> Optional[float]:
 
 
 def summarize(requests: List[Request], wall_s: float) -> Dict[str, float]:
-    """Aggregate per-request telemetry into the BENCH_serving schema.
+    """Aggregate per-request telemetry into the run summary.
 
     TTFT and ITL are split out deliberately: TTFT measures queueing +
     prefill (what the admission policy controls), ITL the gaps *between* a
@@ -814,6 +814,6 @@ def summarize(requests: List[Request], wall_s: float) -> Dict[str, float]:
         "truncated": float(sum(1 for r in requests if r.truncated)),
         # SLO enforcement: requests dropped with a terminal
         # deadline_missed status (scheduler.shed_expired); always present
-        # (0.0 with enforcement off) so BENCH_serving rows track it.
+        # (0.0 with enforcement off) so every summary carries it.
         "shed": float(sum(1 for r in requests if r.state == "shed")),
     }

@@ -3,7 +3,7 @@
 The one non-negotiable rule of timing dispatched JAX computations: sync
 *inside* the loop. ``fn(*args)`` returns as soon as the work is enqueued, so
 a loop that only syncs the last result measures dispatch overhead, not
-execution (the original ``bench_kernels._time`` bug). ``time_callable`` calls
+execution (a bug the retired CPU kernel benchmark once had). ``time_callable`` calls
 ``jax.block_until_ready`` on every iteration and reports min-of-iters (the
 noise-robust statistic schedulers should rank by) alongside the mean.
 

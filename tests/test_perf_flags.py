@@ -1,7 +1,5 @@
-"""Every perf flag must be numerically equivalent to the baseline path.
-
-The §Perf optimizations change schedules/shardings, never math: these
-tests pin that contract so hillclimbing can't silently change results.
+"""The MoE-dispatch and remat flags must be numerically equivalent to
+their defaults: they change schedules and shardings, never math.
 """
 
 import numpy as np
@@ -13,8 +11,6 @@ from repro import configs
 from repro.core import flags
 from repro.core.config import GemminiConfig
 from repro.core.generator import elaborate
-from repro.kernels import ref
-from repro.models import attention as mattn
 from repro.models import transformer as tf
 
 ENGINE = elaborate(GemminiConfig(input_dtype="bf16", acc_dtype="fp32",
@@ -26,51 +22,6 @@ def _reset_flags():
     flags.reset()
     yield
     flags.reset()
-
-
-def test_onehot_cache_update_equals_dus(rng):
-    k = jnp.asarray(rng.standard_normal((2, 16, 4, 8)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, 16, 4, 8)), jnp.float32)
-    kn = jnp.asarray(rng.standard_normal((2, 1, 4, 8)), jnp.float32)
-    vn = jnp.asarray(rng.standard_normal((2, 1, 4, 8)), jnp.float32)
-    pos = jnp.int32(5)
-    c0 = mattn.update_cache(mattn.KVCache(k, v), kn, vn, pos)
-    flags.set_flag("onehot_cache_update", True)
-    c1 = mattn.update_cache(mattn.KVCache(k, v), kn, vn, pos)
-    np.testing.assert_array_equal(np.asarray(c0.k), np.asarray(c1.k))
-    np.testing.assert_array_equal(np.asarray(c0.v), np.asarray(c1.v))
-
-
-def test_gqa_grouped_decode_equals_baseline(rng):
-    q = jnp.asarray(rng.standard_normal((2, 1, 8, 32)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, 64, 2, 32)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, 64, 2, 32)), jnp.float32)
-    pos = jnp.int32(40)
-    y0 = mattn.decode_attention(q, mattn.KVCache(k, v), pos, window=16)
-    flags.set_flag("gqa_grouped_decode", True)
-    y1 = mattn.decode_attention(q, mattn.KVCache(k, v), pos, window=16)
-    np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
-                               rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("flag", ["cache_as_carry", "decode_unroll"])
-@pytest.mark.parametrize("arch", ["gemma2-2b", "mamba2-1.3b"])
-def test_decode_restructure_equals_baseline(rng, flag, arch):
-    cfg = configs.get_smoke(arch)
-    params = tf.init_params(jax.random.PRNGKey(0), cfg)
-    toks = jnp.asarray(rng.integers(0, cfg.vocab, (2, 1)), jnp.int32)
-    state = tf.init_decode_state(cfg, 2, 32, dtype=cfg.dtype)
-    state = state._replace(pos=jnp.asarray(10, jnp.int32))
-    l0, s0 = tf.decode_step(ENGINE, params, cfg, toks, state)
-    flags.set_flag(flag, True)
-    l1, s1 = tf.decode_step(ENGINE, params, cfg, toks, state)
-    np.testing.assert_allclose(
-        np.asarray(l0, np.float32), np.asarray(l1, np.float32),
-        rtol=5e-2, atol=5e-2)      # bf16 reassociation tolerance
-    for a, b in zip(jax.tree.leaves(s0), jax.tree.leaves(s1)):
-        np.testing.assert_allclose(
-            np.asarray(a, np.float32), np.asarray(b, np.float32),
-            rtol=5e-2, atol=5e-2)
 
 
 def test_moe_grouped_dispatch_equals_baseline_on_mesh(run_subprocess):

@@ -9,9 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _oracle import reference_tokens
 from repro.core import flags
 from repro.core.config import GemminiConfig
-from repro.core.generator import elaborate
 from repro.kernels import attention as ak
 from repro.kernels import ref
 from repro.models import attention as mattn
@@ -270,33 +270,6 @@ def test_paged_xla_equals_dense_decode(rng):
                                           np.asarray(yd[0]))
 
 
-def test_paged_xla_grouped_decode_flag_parity(rng):
-    """The gqa_grouped_decode flag branch of the paged gather path stays
-    bit-identical to dense decode_attention under the same flag (the
-    engine-vs-reference exact-match contract must hold either way)."""
-    b, h, kvh, d, page, mp = 2, 4, 2, 16, 8, 3
-    lens = np.array([11, 20], np.int32)
-    kc, vc, pk, pv, tables = _scattered_case(rng, b, h, kvh, d, page, mp,
-                                             lens, poison=0.0)
-    q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-    cache = mattn.PagedKVCache(jnp.asarray(pk), jnp.asarray(pv),
-                               jnp.asarray(tables), jnp.asarray(lens), page,
-                               jnp.int32(0))
-    prev = flags.get("gqa_grouped_decode")
-    flags.set_flag("gqa_grouped_decode", True)
-    try:
-        y = mattn.paged_decode_attention_xla(q, cache)
-        for bb in range(b):
-            dense = mattn.KVCache(jnp.asarray(kc[0, bb:bb + 1]),
-                                  jnp.asarray(vc[0, bb:bb + 1]))
-            yd = mattn.decode_attention(q[bb:bb + 1], dense,
-                                        jnp.int32(int(lens[bb]) - 1))
-            np.testing.assert_array_equal(np.asarray(y[bb]),
-                                          np.asarray(yd[0]))
-    finally:
-        flags.set_flag("gqa_grouped_decode", prev)
-
-
 def _roundtrip(rng, n_layers, li):
     """Prefill scatter, then a decode scatter with slot 1 inactive, into
     layer ``li`` of a stack of ``n_layers`` distinct layers. Returns the
@@ -506,30 +479,12 @@ _TINY = tf.ModelConfig(name="tiny-serve", family="dense", n_layers=2,
                        head_dim=16, d_ff=64, dtype=jnp.float32)
 
 
-def _reference_tokens(model_cfg, params, prompt, gen):
-    engine = elaborate(GemminiConfig(input_dtype="bf16", acc_dtype="fp32",
-                                     output_dtype="bf16"), "xla")
-    t = len(prompt) + model_cfg.n_meta_tokens
-    st = tf.init_decode_state(model_cfg, 1, t + gen, dtype=model_cfg.dtype)
-    st = st._replace(pos=jnp.zeros((), jnp.int32))
-    logits, st = tf.prefill_into_cache(engine, params, model_cfg,
-                                       jnp.asarray(prompt[None]), st)
-    toks, last = [], logits[0, t - 1]
-    for _ in range(gen):
-        nxt = int(jnp.argmax(last))
-        toks.append(nxt)
-        logits, st = tf.decode_step(engine, params, model_cfg,
-                                    jnp.asarray([[nxt]], jnp.int32), st)
-        last = logits[0, -1]
-    return np.asarray(toks, np.int32)
-
-
 def _run_vs_reference(eng, prompts, gens):
     for p, g in zip(prompts, gens):
         eng.submit(p, g)
     rep = eng.run()
     for r, p, g in zip(rep["requests"], prompts, gens):
-        want = _reference_tokens(eng.model_cfg, eng.params, p, g)
+        want = reference_tokens(eng.model_cfg, eng.params, p, g)
         np.testing.assert_array_equal(np.asarray(r["tokens"]).ravel(), want)
     return rep
 
@@ -600,7 +555,7 @@ def test_engine_defrag_preserves_live_requests(rng):
     while eng.sched.has_work:
         eng.step()
     for r, p in zip(eng.requests, prompts):
-        want = _reference_tokens(_TINY, eng.params, p, 5)
+        want = reference_tokens(_TINY, eng.params, p, 5)
         np.testing.assert_array_equal(
             np.asarray([int(t) for t in r.generated]), want)
 
@@ -629,7 +584,7 @@ def test_engine_defrag_under_arena_pressure(rng):
     assert eng.faults.report().get("arena@arena", 0) == 4
     for r, p in zip(eng.requests, prompts):
         assert r.state == "finished"
-        want = _reference_tokens(_TINY, eng.params, p, 6)
+        want = reference_tokens(_TINY, eng.params, p, 6)
         np.testing.assert_array_equal(
             np.asarray([int(t) for t in r.generated]), want)
 
@@ -670,7 +625,8 @@ def test_paged_prefill_xla_twin_bitwise(rng):
     """The explicit-gather XLA twin is bit-identical to the single-pass
     blockwise path for a continuation chunk's rows, in every layer of a
     stack: same KV blocking anchored at 0, same op staging (the
-    serve_decode exact-match gate with chunking on rests on this)."""
+    exact-match gate in test_serving_families.py, which chunks its
+    prompts, rests on this)."""
     h, kvh, d, page, mp, start, tq = 4, 2, 16, 8, 4, 13, 9
     n_layers = 3
     lens = np.array([start + tq], np.int32)

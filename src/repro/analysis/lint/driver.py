@@ -41,10 +41,14 @@ def _gemm_contracts(cfg: GemminiConfig):
                                            max_candidates=16):
             inst = (f"m{m}n{n}k{k}t{plan.tile_m}x{plan.tile_n}x"
                     f"{plan.tile_k}{'b' if has_bias else ''}")
-            yield (kc.gemm_os_contract(cfg, plan, has_bias=has_bias),
-                   cfg, inst)
-            yield (kc.gemm_ws_contract(cfg, plan, has_bias=has_bias),
-                   cfg, inst)
+            # a plain weight (a stack of one) and a layer scan's stack
+            for n_layers in (1, 3):
+                yield (kc.gemm_os_contract(cfg, plan, has_bias=has_bias,
+                                           n_layers=n_layers),
+                       cfg, f"{inst}s{n_layers}")
+                yield (kc.gemm_ws_contract(cfg, plan, has_bias=has_bias,
+                                           n_layers=n_layers),
+                       cfg, f"{inst}s{n_layers}")
             yield (kc.accumulator_epilogue_contract(
                 cfg, plan, m=plan.m, n=plan.n), cfg, inst)
 

@@ -331,8 +331,10 @@ def _require_cfg(ctx: ExecutionContext, op: str) -> GemminiConfig:
 def _gemm(ctx: ExecutionContext, a, b, d=None, **kw):
     """C = act(round_shift(A @ B + D)); under a mesh the GEMM's M rows are
     partitioned so each device resolves (and launches) the per-device
-    plan. See :func:`repro.kernels.ops.gemm_impl` for the backend x
-    tune-mode matrix."""
+    plan. ``b`` may be an (S, K, N) stack with ``layer=`` its index (the
+    weights stay replicated either way). See
+    :func:`repro.kernels.ops.gemm_impl` for the backend x tune-mode
+    matrix."""
     from repro.kernels import ops
     cfg = _require_cfg(ctx, "gemm")
     with ctx._tune_scope():
@@ -351,7 +353,7 @@ def _gemm(ctx: ExecutionContext, a, b, d=None, **kw):
         # M, so materialize it to the M rows only HERE, where each device
         # must see its own slice (the engine kernel streams a full (M, N)
         # D operand either way).
-        db = jnp.broadcast_to(d, (m, b.shape[1]))
+        db = jnp.broadcast_to(d, (m, b.shape[-1]))
         return ctx._shard_call(
             lambda aa, bb, dd: ops.gemm_impl(aa, bb, dd, cfg=cfg,
                                              backend=ctx.impl_backend, **kw),

@@ -174,12 +174,21 @@ def _gemm_common(cfg: GemminiConfig, plan, has_bias: bool):
     return in_dt, acc_dt, out_dt, m, n, k, tm, tn, tk
 
 
+def _weight_layer(n_layers: int) -> int:
+    """The layer the weight's index map is checked at. The GEMM prefetches
+    its layer ``li`` in [0, n_layers) and reads block ``(li*gk + kk, j)``
+    of the stack's ``(n_layers*K, N)`` view: an offset that only adds, so
+    the deepest layer bounds every other."""
+    return n_layers - 1
+
+
 @contract_builder("gemm_os")
-def gemm_os_contract(cfg: GemminiConfig, plan, *,
-                     has_bias: bool = False) -> KernelContract:
+def gemm_os_contract(cfg: GemminiConfig, plan, *, has_bias: bool = False,
+                     n_layers: int = 1) -> KernelContract:
     in_dt, acc_dt, out_dt, m, n, k, tm, tn, tk = \
         _gemm_common(cfg, plan, has_bias)
     gm, gn, gk = plan.grid
+    li = _weight_layer(n_layers)
     semantics = (("arbitrary",) * 3 if cfg.pipeline_depth == 1
                  else ("parallel", "parallel", "arbitrary"))
     d_spec = OperandSpec(
@@ -191,12 +200,13 @@ def gemm_os_contract(cfg: GemminiConfig, plan, *,
         name="gemm_os",
         grid=(("i", gm), ("j", gn), ("kk", gk)),
         semantics=semantics,
+        scalar_prefetch=1,                         # the weight's layer
         inputs=(
             OperandSpec("a", (m, k), (tm, tk),
                         lambda i, j, kk: (i, kk), in_dt,
                         budget="scratchpad"),
-            OperandSpec("b", (k, n), (tk, tn),
-                        lambda i, j, kk: (kk, j), in_dt,
+            OperandSpec("b", (n_layers * k, n), (tk, tn),
+                        lambda i, j, kk: (li * gk + kk, j), in_dt,
                         budget="scratchpad"),
             d_spec,
         ),
@@ -209,11 +219,12 @@ def gemm_os_contract(cfg: GemminiConfig, plan, *,
 
 
 @contract_builder("gemm_ws")
-def gemm_ws_contract(cfg: GemminiConfig, plan, *,
-                     has_bias: bool = False) -> KernelContract:
+def gemm_ws_contract(cfg: GemminiConfig, plan, *, has_bias: bool = False,
+                     n_layers: int = 1) -> KernelContract:
     in_dt, acc_dt, out_dt, m, n, k, tm, tn, tk = \
         _gemm_common(cfg, plan, has_bias)
     gm, gn, gk = plan.grid
+    li = _weight_layer(n_layers)
     d_spec = OperandSpec(
         "d", (m if has_bias else 1, n), (tm if has_bias else 1, tn),
         (lambda j, i, kk: (i, j)) if has_bias
@@ -223,9 +234,10 @@ def gemm_ws_contract(cfg: GemminiConfig, plan, *,
         name="gemm_ws",
         grid=(("j", gn), ("i", gm), ("kk", gk)),   # weight-major
         semantics=("parallel", "parallel", "arbitrary"),
+        scalar_prefetch=1,                         # the weight's layer
         inputs=(
-            OperandSpec("b", (k, n), (tk, tn),
-                        lambda j, i, kk: (kk, j), in_dt,
+            OperandSpec("b", (n_layers * k, n), (tk, tn),
+                        lambda j, i, kk: (li * gk + kk, j), in_dt,
                         budget="scratchpad"),
             OperandSpec("a", (m, k), (tm, tk),
                         lambda j, i, kk: (i, kk), in_dt,

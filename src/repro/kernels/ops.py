@@ -72,16 +72,31 @@ def _resolve_plan(cfg: GemminiConfig, m: int, n: int, k: int, *,
                               has_bias=has_bias)
 
 
+def weight_at(b: jnp.ndarray, layer) -> jnp.ndarray:
+    """``b`` itself where ``layer`` is None, else layer ``layer`` of the
+    ``(S, K, N)`` stack ``b``, sliced out (XLA fuses the slice into a dot
+    that reads it; the engine kernel reads the stack in place instead)."""
+    if layer is None:
+        return b
+    return jax.lax.dynamic_index_in_dim(b, layer, 0, keepdims=False)
+
+
 def gemm_impl(a: jnp.ndarray, b: jnp.ndarray, d: Optional[jnp.ndarray] = None,
               *, cfg: GemminiConfig, plan: Optional[TilePlan] = None,
               dataflow: Optional[Dataflow] = None, shift: int = 0,
               activation: Activation = Activation.NONE,
-              backend: Backend = "xla") -> jnp.ndarray:
+              backend: Backend = "xla", layer=None) -> jnp.ndarray:
     """C = act(round_shift(A @ B + D)) on the elaborated instance.
 
     a: (M, K), b: (K, N), d: broadcastable (1|M, N) bias at acc dtype.
     Reached as ``ctx.gemm(a, b, d, ...)``; the context supplies ``cfg``
     and ``backend`` and (under a mesh) shards M.
+
+    A stacked weight -- b: (S, K, N) with ``layer`` an int32 scalar (may be
+    traced) -- multiplies by ``b[layer]``. The kernel reads that layer
+    from the stack in place where the plan pads neither K nor N; otherwise
+    the layer is sliced out and padded as a 2-D weight is. The stacked
+    entry serves inference (the paged layer scan) and has no VJP.
 
     backend x tune-mode matrix (``plan`` given short-circuits both):
 
@@ -103,30 +118,40 @@ def gemm_impl(a: jnp.ndarray, b: jnp.ndarray, d: Optional[jnp.ndarray] = None,
     ==========  ===========================================================
     """
     m, k = a.shape
-    k2, n = b.shape
+    k2, n = b.shape[-2:]
     if k != k2:
         raise ValueError(f"inner dims mismatch: {a.shape} @ {b.shape}")
+    if (b.ndim == 3) != (layer is not None):
+        raise ValueError(f"weight {b.shape} with layer {layer!r}: a stack "
+                         f"(S, K, N) takes a layer index, a (K, N) none")
     if backend == "xla":
-        return ref_ops.gemm_ref(a, b, d, acc_dtype=cfg.acc_jnp,
+        return ref_ops.gemm_ref(a, weight_at(b, layer), d,
+                                acc_dtype=cfg.acc_jnp,
                                 out_dtype=cfg.output_jnp, shift=shift,
                                 activation=activation)
 
-    def run(a, b, d, plan=None):
+    def run(a, b, d, plan=None, layer=None):
         m, k = a.shape
-        n = b.shape[1]
+        n = b.shape[-1]
         plan = plan or _resolve_plan(cfg, m, n, k, dataflow=dataflow,
                                      has_bias=d is not None)
+        if (plan.k, plan.n) != (k, n):
+            # the plan pads the weight: pad this layer's matrix alone
+            b, layer = _pad2(weight_at(b, layer), plan.k, plan.n), None
         ap = _pad2(a, plan.m, plan.k)
-        bp = _pad2(b, plan.k, plan.n)
         dp = None
         if d is not None:
             dp = _pad2(jnp.broadcast_to(d, (m, n)).astype(cfg.acc_jnp),
                        plan.m, plan.n)
-        out = gemm_kernel.gemm(ap, bp, dp, plan, cfg, dataflow=dataflow,
-                               shift=shift, activation=activation,
+        out = gemm_kernel.gemm(ap, b, dp, plan, cfg, dataflow=dataflow,
+                               layer=0 if layer is None else layer,
+                               shift=shift,
+                               activation=activation,
                                interpret=(backend == "interpret"))
         return out[:m, :n]
 
+    if layer is not None:
+        return run(a, b, d, plan, layer)
     if shift == 0 and activation is Activation.NONE and \
             jnp.issubdtype(cfg.input_jnp, jnp.floating):
         return _linear_gemm(run, a, b, d, plan)
@@ -168,7 +193,7 @@ def matmul_impl(a: jnp.ndarray, b: jnp.ndarray, *, cfg: GemminiConfig,
     """Batched-LHS matmul: a may be (..., K); collapsed to 2D for the
     engine. Pure shape sugar over :func:`gemm_impl` -- backend and
     tune-mode behavior are exactly gemm's matrix with
-    M = prod(leading dims)."""
+    M = prod(leading dims), and ``b`` may be a stack read at ``layer=``."""
     lead = a.shape[:-1]
     y = gemm_impl(a.reshape(-1, a.shape[-1]), b, cfg=cfg, backend=backend,
                   **kw)

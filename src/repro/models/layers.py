@@ -13,12 +13,13 @@ dicts of jnp arrays); ``apply`` functions are free of Python state.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.generator import GemminiInstance
+from repro.kernels import ops
 
 Params = Dict[str, Any]
 
@@ -78,16 +79,31 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, *, base: float = 10000.0,
 # ---------------------------------------------------------------------------
 # engine-backed projections
 # ---------------------------------------------------------------------------
-def project(engine, x: jnp.ndarray, w: jnp.ndarray,
+class StackedWeight(NamedTuple):
+    """Layer ``layer`` of a stack of projection weights ``(L, d_in,
+    d_out)``, left in the stack: the paged layer scan hands each block
+    these in place of per-layer slices, and the engine GEMM reads the
+    layer from the stack by index, so no layer's matrix is copied out."""
+
+    stack: jnp.ndarray
+    layer: jnp.ndarray                 # int32 scalar, may be traced
+
+
+def project(engine, x: jnp.ndarray, w: Union[jnp.ndarray, StackedWeight],
             b: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """y = x @ w (+ b) on the Gemmini engine; x: (..., d_in), w: (d_in, d_out).
+    """y = x @ w (+ b) on the Gemmini engine; x: (..., d_in), w: (d_in, d_out)
+    or a :class:`StackedWeight` of that shape.
 
     ``engine`` is the dispatch value: an elaborated
     :class:`GemminiInstance` or a bare
     :class:`repro.core.context.ExecutionContext` -- both expose
     ``backend`` and ``matmul``, and a mesh'd context runs the engine
     kernel in shard_map at per-device M."""
+    layer = None
+    if isinstance(w, StackedWeight):
+        w, layer = w.stack, w.layer
     if engine.backend == "xla":
+        w = ops.weight_at(w, layer)
         # Float LM path: keep XLA free to fuse/partition; numerics equal to
         # the engine's float datapath (fp32 accumulate). "xla_twin"
         # deliberately does NOT take this shortcut: the degraded-mode twin
@@ -103,7 +119,7 @@ def project(engine, x: jnp.ndarray, w: jnp.ndarray,
     # at acc dtype inside the fused epilogue, Gemmini's D matrix) instead
     # of a separate post-engine add -- and the tile plan resolves with
     # has_bias=True, the same fingerprint warm_model_plans pre-populates.
-    return engine.matmul(x, w, d=b)
+    return engine.matmul(x, w, d=b, layer=layer)
 
 
 def mlp_init(key, d: int, d_ff: int, *, dtype=jnp.bfloat16,

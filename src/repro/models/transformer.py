@@ -204,6 +204,15 @@ def _block_init(key, cfg: ModelConfig) -> Params:
 # MLP do route through the engine and are covered.)
 _PROJ_KEYS = frozenset({"wq", "wk", "wv", "wo", "wi", "wg", "router",
                         "in_proj", "out_proj", "unembed", "heads"})
+# Of those, the ones inside a block: stacked (L, d_in, d_out) in
+# params["blocks"], read by ``layers.project`` alone.
+_STACKED_PROJ_KEYS = _PROJ_KEYS - {"unembed", "heads"}
+
+
+def _names(path) -> tuple:
+    """The dict keys along a pytree path."""
+    return tuple(p.key for p in path
+                 if isinstance(p, jax.tree_util.DictKey))
 
 
 def model_gemm_shapes(cfg: ModelConfig, batch: int, seq: int, *,
@@ -226,10 +235,6 @@ def model_gemm_shapes(cfg: ModelConfig, batch: int, seq: int, *,
                             jax.random.PRNGKey(0))
     ms = [batch * seq] + ([batch] if include_decode else [])
     leaves = jax.tree_util.tree_flatten_with_path(shapes)[0]
-
-    def _names(path):
-        return tuple(p.key for p in path
-                     if isinstance(p, jax.tree_util.DictKey))
 
     # Leaf names present under each parent dict, to detect sibling biases.
     siblings: dict = {}
@@ -734,22 +739,35 @@ def _scan_paged(cfg: ModelConfig, params: Params, h: jnp.ndarray,
     The stacked KV pools ride the scan's carry whole: each layer writes
     its tokens into them in place and its attention reads its own pages
     straight from the stack, by layer index. No layer's pool is sliced
-    out or stacked back, which would copy the arena every layer. The SSM
-    slot state (small, per slot) rides ``xs``/``ys``.
+    out or stacked back, which would copy the arena every layer. The
+    projection matrices stay in their stacks too: the body closes over
+    them and hands each block :class:`layers.StackedWeight` leaves, which
+    the engine GEMM reads by layer index. Norms, biases and the SSM slot
+    state (small, per layer or per slot) ride ``xs``/``ys``.
 
     ``cache(kv_k, kv_v, li)`` builds layer ``li``'s
     :class:`attn.PagedKVCache`; ``block(h, bp, win, base, kvc, conv, st)``
     runs one block and returns ``(h, kvc, conv, st)``.
     """
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(params["blocks"])
+    in_stack = [_names(path)[-1] in _STACKED_PROJ_KEYS and leaf.ndim == 3
+                for path, leaf in leaves]
+
     def body(carry, xs):
         h, kv_k, kv_v = carry
-        bp, win, base, li, conv, st = xs
+        sliced, win, base, li, conv, st = xs
+        rest = iter(sliced)
+        bp = treedef.unflatten([
+            layers.StackedWeight(leaf, li) if stacked else next(rest)
+            for (_, leaf), stacked in zip(leaves, in_stack)])
         kvc = cache(kv_k, kv_v, li) if kv_k is not None else None
         h, kvc, conv, st = block(h, bp, win, base, kvc, conv, st)
         kv_k, kv_v = (kvc.k, kvc.v) if kvc is not None else (None, None)
         return (h, kv_k, kv_v), (conv, st)
 
-    xs = (params["blocks"], windows, bases,
+    sliced = [leaf for (_, leaf), stacked in zip(leaves, in_stack)
+              if not stacked]
+    xs = (sliced, windows, bases,
           jnp.arange(cfg.n_layers, dtype=jnp.int32), state.conv, state.ssm)
     (h, kv_k, kv_v), (conv, st) = jax.lax.scan(
         body, (h, state.kv_k, state.kv_v), xs)

@@ -15,6 +15,7 @@ the same tests.
 
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -231,3 +232,62 @@ def test_paged_step_keeps_arena_in_place(step, one_chip):
                     " copy(" in rhs or '"AllocateBuffer"' in rhs)):
             offenders.append(line.strip()[:160])
     assert not offenders, "\n".join(offenders)
+
+
+# -- the paged steps read the block weights in place -------------------------
+# one layer's projection matrices (q, k, v, o; gate, up; down) and their
+# stacks' (STEP_LAYERS*K, N) views, which the engine GEMM reads by index
+_LAYER_WEIGHTS = ((D, D), (D, D_FF), (D_FF, D))
+_STACK_VIEWS = {(STEP_LAYERS * k, n) for k, n in _LAYER_WEIGHTS}
+_HEAD = (D, 151936)                     # the untied head, a stack of one
+
+
+def _engine_gemms(hlo: str):
+    """(operand shapes, in the layer scan's body) of every engine GEMM
+    custom call: the Mosaic calls whose first operand is the prefetched
+    layer, an s32[1], followed by A, B and D in either dataflow's order."""
+    out = []
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        opnds = line.split("operand_layout_constraints={", 1)[1]
+        opnds = opnds.split("}}", 1)[0]
+        shapes = [tuple(int(x) for x in dims.split(",") if x)
+                  for dims in re.findall(r"\w+\[([\d,]*)\]", opnds)]
+        if not opnds.startswith("s32[1]{") or len(shapes) != 4:
+            continue
+        out.append((shapes, "/while/body/" in line))
+    return out
+
+
+@pytest.mark.parametrize("step", ["chunk", "decode"])
+def test_paged_step_reads_weights_in_place(step, one_chip):
+    """The paged steps read each layer's projection matrices from the
+    stacked block weights where they lie: no instruction produces one
+    layer's matrix, every engine GEMM takes a stack's (L*K, N) view or
+    the head's one-deep stack, and all 7 projections of a layer do."""
+    hlo = _step_program(step, 56, one_chip).as_text()
+    offenders = [line.strip()[:160] for line in hlo.splitlines()
+                 if any(line.partition(" = ")[2].startswith(
+                     f"bf16[{k},{n}]{{") for k, n in _LAYER_WEIGHTS)]
+    assert not offenders, "\n".join(offenders)
+    # the stacks and their views are free: only a parameter, a tuple
+    # element or a bitcast may produce one, or the prefetch of a stack
+    # small enough for fast memory (S(1)); never a copy of it in HBM
+    stacks = [f"bf16[{STEP_LAYERS},{k},{n}]{{" for k, n in _LAYER_WEIGHTS]
+    stacks += [f"bf16[{k},{n}]{{" for k, n in _STACK_VIEWS]
+    offenders = [line.strip()[:160] for line in hlo.splitlines()
+                 if any(line.partition(" = ")[2].startswith(s)
+                        for s in stacks)
+                 and not re.search(r"\} (parameter|get-tuple-element|"
+                                   r"bitcast)\(|S\(1\)\} copy-done\(", line)]
+    assert not offenders, "\n".join(offenders)
+
+    gemms = _engine_gemms(hlo)
+    weights = [next((s for s in shapes if s in _STACK_VIEWS | {_HEAD}),
+                    shapes) for shapes, _ in gemms]
+    assert all(w in _STACK_VIEWS | {_HEAD} for w in weights), weights
+    in_place = sum(w in _STACK_VIEWS and in_body
+                   for w, (_, in_body) in zip(weights, gemms))
+    assert in_place == 7, f"{in_place} of a layer's 7 projections in place"
+    assert weights.count(_HEAD) == 1
